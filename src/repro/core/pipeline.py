@@ -338,7 +338,6 @@ def map_chunk(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
 @functools.lru_cache(maxsize=None)
 def _sharded_chunk_fn(cfg: MarsConfig, mesh, plan: stages.Plan,
                       index_keys: Optional[Tuple[str, ...]] = None):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
@@ -372,11 +371,11 @@ def _sharded_chunk_fn(cfg: MarsConfig, mesh, plan: stages.Plan,
     else:
         index_spec = P()
     counter_spec = {k: P() for k in stages.CHUNK_COUNTER_SCHEMA}
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axes, None), index_spec, P()),
-                   out_specs=(P(axes), P(axes), P(axes), P(axes),
-                              counter_spec),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axes, None), index_spec, P()),
+                       out_specs=(P(axes), P(axes), P(axes), P(axes),
+                                  counter_spec),
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -428,9 +428,7 @@ class HotTileCache:
             s = self.n_slots + j
             if self._slot_tile[s] == int(t):
                 continue
-            bstart, ent = self._fetch_tile(int(t))
-            self._dev_bstart = self._dev_bstart.at[s].set(jnp.asarray(bstart))
-            self._dev_ent = self._dev_ent.at[:, s, :].set(jnp.asarray(ent))
+            self._write_slot(s, *self._fetch_tile(int(t)))
             self._slot_tile[s] = int(t)
             self._slot_touch[s] = 0
             self.replica_loads += 1
@@ -488,11 +486,17 @@ class HotTileCache:
     def _load_slot(self, s: int, t: int) -> None:
         # fetch (verify + retry) BEFORE touching device state: a failed
         # page-in raises here and leaves every persistent slot unchanged
-        bstart, ent = self._fetch_tile(t)
-        self._dev_bstart = self._dev_bstart.at[s].set(jnp.asarray(bstart))
-        self._dev_ent = self._dev_ent.at[:, s, :].set(jnp.asarray(ent))
+        self._write_slot(s, *self._fetch_tile(t))
         self._slot_tile[s] = t
         self._slot_touch[s] = 0
+
+    def _write_slot(self, s: int, bstart, ent) -> None:
+        """Functional update of slot ``s``'s planes.  The updated arrays
+        keep the cache's sharding (replicated over the mesh, if any)."""
+        self._dev_bstart = self._put(
+            self._dev_bstart.at[s].set(self._put(jnp.asarray(bstart))))
+        self._dev_ent = self._put(
+            self._dev_ent.at[:, s, :].set(self._put(jnp.asarray(ent))))
 
     def _view(self, bstart, ent, tile_slot, chunk_hits, chunk_misses):
         paged = chunk_misses * self.tiered.tile_nbytes

@@ -16,7 +16,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(fn_stage: Callable, x: jnp.ndarray, stage_params,
@@ -71,6 +70,6 @@ def pipeline_apply(fn_stage: Callable, x: jnp.ndarray, stage_params,
         return outs.reshape(x_local.shape)
 
     spec_p = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    fn = shard_map(stage_body, mesh=mesh, in_specs=(spec_p, P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh, in_specs=(spec_p, P()),
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, x)

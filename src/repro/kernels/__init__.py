@@ -5,19 +5,10 @@ Each kernel package has:
     ops.py     — jit'd public wrapper (padding, dtype plumbing, vmap rules)
     ref.py     — pure-jnp oracle the kernel is tested against
 
-Kernels target TPU; on this CPU-only container they run (and are tested)
-in interpret mode.  `INTERPRET` flips automatically.
+Kernels compile with Mosaic on a TPU.  On the CPU backend (the test suite)
+they run in Pallas interpret mode: ``INTERPRET`` is set from the backend at
+import, and a test that compiles for a described TPU flips it itself.
 """
 import jax
-from jax.experimental.pallas import tpu as _pltpu
 
 INTERPRET = jax.default_backend() == "cpu"
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-CompilerParams = getattr(_pltpu, "CompilerParams",
-                         getattr(_pltpu, "TPUCompilerParams", None))
-if CompilerParams is None:
-    def CompilerParams(**_kw):  # noqa: F811 — clear failure over NoneType
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; unsupported jax version")

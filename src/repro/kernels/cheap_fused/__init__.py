@@ -1,2 +1,2 @@
 from repro.kernels.cheap_fused.ops import cheap_fused  # noqa: F401
-from repro.kernels.cheap_fused.cheap_fused import FusedTile, tune_tile  # noqa: F401
+from repro.kernels.cheap_fused.cheap_fused import DEFAULT_TILE, FusedTile  # noqa: F401

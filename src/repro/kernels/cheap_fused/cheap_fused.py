@@ -1,34 +1,44 @@
 """Fused cheap-phase mega-kernel: detect -> quantize -> seed -> query -> vote.
 
-One `pl.pallas_call` executes the whole cheap phase for a block of reads
-without leaving the kernel.  The quantized signal block is staged into VMEM
-by the grid pipeline; event means, quantized symbols and seed keys live in
-registers/scratch instead of round-tripping through HBM between stage
-launches; and the packed 2-plane index (`bucket_start` + `entries_packed`)
-stays in `pltpu.ANY` memory and is streamed tile-by-tile through VMEM
-scratch with double-buffered `pltpu.make_async_copy` DMA — the
-`emit_pipeline` idiom spelled out by hand: while tile t is being probed
-(one-hot matmul gather, split into exact hi/lo 16-bit f32 planes), the DMA
-for tile t+1 is already in flight.  This mirrors the HotTileCache's
-host->device prefetch one level down, and MARS's flash-load/compute overlap
-one level up.
+One `pl.pallas_call` executes the whole cheap phase for one read per grid
+step without leaving the kernel.  The quantized signal row is staged into
+VMEM by the grid pipeline; event means, quantized symbols and seed keys
+live in registers/scratch instead of round-tripping through HBM between
+stage launches; and the two index tables stay in `pl.ANY` memory and are
+streamed tile-by-tile through VMEM scratch with double-buffered
+`pltpu.make_async_copy` DMA — the `emit_pipeline` idiom spelled out by
+hand: while tile t is being probed (one-hot matmul gather, split into exact
+hi/lo 16-bit f32 planes), the DMA for tile t+1 is already in flight.  This
+mirrors the HotTileCache's host->device prefetch one level down, and
+MARS's flash-load/compute overlap one level up.
+
+The two tables are (2, N) int32 row pairs, so each is ONE sweep: the
+bucket table holds [bucket_start[b], bucket_start[b+1]] (both boundaries
+of bucket b) and the entry table is the packed entry plane
+[key|cnt, t_pos].  A read's queries lie on the lane axis — E buckets, then
+E*H entry slots — and each probe is a (4, bt) @ (bt, Q) matmul against the
+transposed one-hot, so no value ever changes layout between lanes and
+sublanes.
 
 The math is copied operation-for-operation from the per-stage path so the
 fusion is bit-identical:
 
-    detect     kernels/event_detect/event_detect.py::_kernel
+    detect     kernels/event_detect/event_detect.py::detect_rows (shared)
     quantize   core/quantization.py::quantize_events_fixed
     seed       core/hashing.py::pack_seeds (+ mix32, minimizer_mask)
     query      core/seeding.py::query_index / unpack_entries / match_entries
     vote       core/vote.py::vote_filter
 
-Tiling is chosen by `tune_tile` — a deliberately tiny grid in interpret
-mode (CPU CI), MXU/warp-friendly blocks for Mosaic (TPU) and Triton (GPU).
+The vote histogram is built ``_VOTE_CHUNK`` bins at a time with integer
+lane/sublane reductions, so no (E*H, vote_bins) one-hot is ever whole.
+Tile width is `DEFAULT_TILE`, sized for scoped VMEM on Mosaic; tests
+pass smaller tiles.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,9 +48,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import kernels as K
 from repro.core import hashing
 from repro.core.vote import DIAG_SHIFT
+from repro.kernels import lanes
+from repro.kernels.event_detect.event_detect import detect_rows
 
-_NEG = -3.0e38  # ~f32 min; avoids jnp.finfo weak-type traps inside pallas
 _HIGHEST = jax.lax.Precision.HIGHEST
+_VOTE_CHUNK = 256       # vote bins per histogram pass
 
 # Column order of the fused kernel's per-read counter plane.
 COUNTER_COLS = (
@@ -52,91 +64,48 @@ COUNTER_COLS = (
 
 @dataclasses.dataclass(frozen=True)
 class FusedTile:
-    """Grid/block-shape choice for the mega-kernel.
+    """Block-shape choice for the mega-kernel.
 
-    r_blk — reads per kernel program (grid = n_reads_padded // r_blk)
-    bt    — index-tile width in entries for the double-buffered DMA sweep
+    bt — index-tile width in entries for the double-buffered DMA sweeps;
+    the entry sweep's one-hot is (bt, E*H) f32 in VMEM.
     """
-    r_blk: int
     bt: int
 
 
-def tune_tile(platform: str) -> FusedTile:
-    """Autotuning hook: pick grid/block shapes per lowering target.
+# One geometry for Mosaic and interpret mode: on Mosaic the entry sweep's
+# (bt, E*H) one-hot and its compare stay within scoped VMEM at D1 widths
+# (E*H = 3072); in interpret mode the CPU parity suite still walks the
+# multi-tile and partial-tail paths.
+DEFAULT_TILE = FusedTile(bt=512)
 
-    `platform` is `jax.default_backend()` ("tpu" / "gpu" / "cpu") or the
-    literal "interpret".  Interpret mode keeps the grid deliberately small
-    so the CPU CI parity suite stays fast; the Mosaic and Triton entries
-    are the seed points a real autotune sweep would refine on hardware.
+
+def _repeat(x, h):
+    """(1, E) -> (1, E*h): each lane repeated h times, in order."""
+    e = x.shape[1]
+    return jnp.broadcast_to(x.reshape(1, e, 1), (1, e, h)).reshape(1, e * h)
+
+
+def _sweep_gather(src_ref, buf, sem, n_tiles, bt, q_row):
+    """Double-buffered DMA sweep-gather over a (2, n_tiles*bt) table.
+
+    Streams the table tile-by-tile from `pl.ANY` memory into the 2-slot
+    VMEM scratch `buf`, starting the copy of tile t+1 before probing tile
+    t (hand-rolled `pltpu.emit_pipeline`).  Each tile is probed with a
+    one-hot f32 matmul gather, exact because the int32 values are split
+    into hi/lo 16-bit planes (<= 2^16 in f32) and out-of-tile queries
+    contribute zero columns.
+
+    q_row: (1, Q) int32 global column indices (pre-clipped in range).
+    Returns (2, Q) int32: both table words of every queried column.
     """
-    if platform in ("cpu", "interpret"):
-        return FusedTile(r_blk=1, bt=512)
-    if platform == "tpu":
-        # Mosaic: 8-row blocks keep the one-hot matmuls MXU-shaped; 2048-
-        # entry tiles amortize DMA issue latency against VMEM pressure.
-        return FusedTile(r_blk=8, bt=2048)
-    # Triton (GPU): smaller tiles — gathers are shared-memory bound.
-    return FusedTile(r_blk=4, bt=1024)
-
-
-def _shift_left(x, d, fill):
-    """x[:, i+d] with `fill` entering on the right (lanes-axis shift)."""
-    if d == 0:
-        return x
-    rows = x.shape[0]
-    pad = jnp.full((rows, d), fill, dtype=x.dtype)
-    return jnp.concatenate([x[:, d:], pad], axis=1)
-
-
-def _shift_right(x, d, fill):
-    """x[:, i-d] with `fill` entering on the left."""
-    if d == 0:
-        return x
-    rows = x.shape[0]
-    pad = jnp.full((rows, d), fill, dtype=x.dtype)
-    return jnp.concatenate([pad, x[:, :-d]], axis=1)
-
-
-def _roll_left(x, d):
-    """Circular jnp.roll(x, -d, axis=1) — wraparound must match pack_seeds
-    exactly: raw t_pos is compared at *invalid* seed slots too, so the
-    garbage keys there still have to be the same garbage."""
-    if d == 0:
-        return x
-    return jnp.concatenate([x[:, d:], x[:, :d]], axis=1)
-
-
-def _prefix_sum(x):
-    """Inclusive Hillis-Steele prefix sum along the lanes axis (int32)."""
-    span = x.shape[1]
-    d = 1
-    while d < span:
-        x = x + _shift_right(x, d, 0)
-        d *= 2
-    return x
-
-
-def _sweep_gather(src_ref, buf, sem, n_tiles, bt, qcol, nrows):
-    """Double-buffered DMA sweep-gather over a (nrows, n_tiles*bt) table.
-
-    Streams the table tile-by-tile from `pltpu.ANY` memory into the
-    2-slot VMEM scratch `buf`, starting the copy of tile t+1 before
-    probing tile t (hand-rolled `pltpu.emit_pipeline`).  Each tile is
-    probed with a one-hot f32 matmul gather, exact because the int32
-    values are split into hi/lo 16-bit planes (<= 2^16 in f32) and
-    out-of-tile queries contribute zero rows.
-
-    qcol: (Q, 1) int32 global column indices (pre-clipped in range).
-    Returns (Q, nrows) int32 gathered values.
-    """
-    q = qcol.shape[0]
+    q = q_row.shape[1]
 
     def dma(slot, t):
         return pltpu.make_async_copy(
             src_ref.at[:, pl.ds(t * bt, bt)], buf.at[slot], sem.at[slot])
 
     dma(0, 0).start()
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (q, bt), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, q), 0)
 
     def body(t, acc):
         slot = jax.lax.rem(t, 2)
@@ -146,17 +115,17 @@ def _sweep_gather(src_ref, buf, sem, n_tiles, bt, qcol, nrows):
             dma(1 - slot, t + 1).start()
 
         dma(slot, t).wait()
-        tab = buf[slot]                                   # (nrows, bt) i32
-        onehot = (qcol - t * bt == lanes).astype(jnp.float32)
-        hi = jnp.right_shift(tab, 16).astype(jnp.float32)
-        lo = jnp.bitwise_and(tab, 0xFFFF).astype(jnp.float32)
-        planes = jnp.concatenate([hi, lo], axis=0).T      # (bt, 2*nrows)
-        return acc + jax.lax.dot(onehot, planes, precision=_HIGHEST)
+        tab = buf[slot]                                   # (2, bt) int32
+        onehot = (q_row - t * bt == rows).astype(jnp.float32)   # (bt, Q)
+        planes = jnp.concatenate(
+            [jnp.right_shift(tab, 16).astype(jnp.float32),
+             jnp.bitwise_and(tab, 0xFFFF).astype(jnp.float32)], axis=0)
+        return acc + jax.lax.dot(planes, onehot, precision=_HIGHEST)
 
-    acc = jax.lax.fori_loop(
-        0, n_tiles, body, jnp.zeros((q, 2 * nrows), jnp.float32))
-    return (jnp.left_shift(acc[:, :nrows].astype(jnp.int32), 16)
-            | acc[:, nrows:].astype(jnp.int32))
+    acc = jax.lax.fori_loop(0, n_tiles, body,
+                            jnp.zeros((4, q), jnp.float32))
+    return (jnp.left_shift(acc[0:2].astype(jnp.int32), 16)
+            | acc[2:4].astype(jnp.int32))
 
 
 def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
@@ -165,60 +134,18 @@ def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
             seed_w, seed_q, minimizer_r, levels, clip_q, step_q,
             n_buckets, n_entries, thresh_freq, use_freq, use_vote,
             vlog2, nbins, thresh_vote, bt, nt_bs, nt_ent):
-    x = xq_ref[...]                                       # (RB, S) int32
-    rb, s = x.shape
     e, h = n_ev_max, hits
     eh = e * h
-    f32, i32 = jnp.float32, jnp.int32
+    i32 = jnp.int32
 
-    # ---- detect (event_detect._kernel, generalized to RB rows) ----------
-    xx = x * x
-    sum_l = jnp.zeros_like(x)
-    sum_r = jnp.zeros_like(x)
-    sq_l = jnp.zeros_like(x)
-    sq_r = jnp.zeros_like(x)
-    for d in range(tw):
-        sum_l = sum_l + _shift_right(x, d + 1, 0)
-        sq_l = sq_l + _shift_right(xx, d + 1, 0)
-        sum_r = sum_r + _shift_left(x, d, 0)
-        sq_r = sq_r + _shift_left(xx, d, 0)
-    diff = (sum_r - sum_l) >> 2
-    ssd_l = tw * sq_l - sum_l * sum_l
-    ssd_r = tw * sq_r - sum_r * sum_r
-    lhs = diff * diff * tw
-    rhs = tau2 * (((ssd_l + ssd_r) >> 4) + eps)
-    above = lhs > rhs
-    score = lhs.astype(f32) / (rhs.astype(f32) + 1.0)
+    # ---- detect (event_detect.detect_rows on this read's row) ------------
+    means, nev = detect_rows(xq_ref[...], E=e, w=tw, tau2=tau2, eps=eps,
+                             peak_r=peak_r, frac_bits=frac_bits)
 
-    wmax = score
-    for d in range(1, peak_r + 1):
-        wmax = jnp.maximum(wmax, _shift_left(score, d, _NEG))
-        wmax = jnp.maximum(wmax, _shift_right(score, d, _NEG))
-    lmax = score
-    for d in range(1, peak_r + 1):
-        lmax = jnp.maximum(lmax, _shift_right(score, d, _NEG))
-    boundary = (score >= wmax) & (score >= lmax) & above
-
-    eid = _prefix_sum(boundary.astype(i32))
-    nev = jnp.minimum(eid[:, s - 1:s] + 1, e)             # (RB, 1)
-    eid = jnp.minimum(eid, e - 1)
-
-    xf = x.astype(f32)
-    ones = jnp.ones((1, s), f32)
-    bins_se = jax.lax.broadcasted_iota(i32, (s, e), 1)
-    rows = []
-    for r in range(rb):
-        onehot = (eid[r:r + 1].reshape(s, 1) == bins_se).astype(f32)
-        sums = jax.lax.dot(xf[r:r + 1], onehot, precision=_HIGHEST)
-        cnts = jax.lax.dot(ones, onehot, precision=_HIGHEST)
-        rows.append(sums / jnp.maximum(cnts, 1.0) / float(1 << frac_bits))
-    means = rows[0] if rb == 1 else jnp.concatenate(rows, axis=0)
-
-    # ---- quantize (quantization.quantize_events_fixed, row-vectorized) --
-    eq = jnp.round(means * (1 << frac_bits)).astype(i32)  # (RB, E)
-    iota_e = jax.lax.broadcasted_iota(i32, (rb, e), 1)
-    ev_valid = iota_e < nev
-    v = ev_valid.astype(i32)
+    # ---- quantize (quantization.quantize_events_fixed) -------------------
+    eq = jnp.round(means * (1 << frac_bits)).astype(i32)  # (1, E)
+    iota_e = lanes.lane_iota(eq)
+    v = (iota_e < nev).astype(i32)
     n = jnp.maximum(jnp.sum(v, axis=1, keepdims=True), 1)
     mean = jnp.sum(eq * v, axis=1, keepdims=True) // n
     dlt = eq - mean
@@ -231,101 +158,102 @@ def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
     z_q = jnp.clip((dlt << frac_bits) // std, -clip_q, clip_q - 1)
     sym = jnp.clip((z_q + clip_q) // max(step_q, 1), 0, levels - 1)
 
-    # ---- seed (hashing.pack_seeds + mix32 + minimizer_mask) -------------
+    # ---- seed (hashing.pack_seeds + mix32 + minimizer_mask) --------------
     su = sym.astype(jnp.uint32)
-    key = jnp.zeros((rb, e), jnp.uint32)
+    key = jnp.zeros_like(su)
     for j in range(seed_w):
-        key = (key << seed_q) | _roll_left(su, j)
+        key = (key << seed_q) | lanes.roll_left(su, j)   # circular, as jnp.roll
     key = hashing.mix32(key)
     seed_valid = (iota_e + seed_w) <= nev
     if minimizer_r > 0:
-        big = jnp.uint32(0xFFFFFFFF)
-        kv = jnp.where(seed_valid, key, big)
+        # unsigned min in the signed domain (flip the sign bit): Mosaic has
+        # no unsigned vector min
+        kv = jnp.where(seed_valid,
+                       jax.lax.bitcast_convert_type(key, i32) ^ (-1 << 31),
+                       (1 << 31) - 1)
         wmin = kv
         for d in range(1, minimizer_r + 1):
-            wmin = jnp.minimum(wmin, _shift_left(kv, d, big))
-            wmin = jnp.minimum(wmin, _shift_right(kv, d, big))
+            wmin = jnp.minimum(wmin, lanes.shift_left(kv, d, (1 << 31) - 1))
+            wmin = jnp.minimum(wmin, lanes.shift_right(kv, d, (1 << 31) - 1))
         seed_valid = seed_valid & (kv == wmin)
 
-    # ---- query (seeding.query_index on the streamed 2-plane index) ------
+    # ---- query (seeding.query_index on the two streamed tables) ----------
     mask_u = jnp.uint32(n_buckets - 1)
-    bucket = (key & mask_u).astype(i32)                   # (RB, E)
-    qb = jnp.concatenate([bucket, bucket + 1], axis=1)    # (RB, 2E)
-    se = _sweep_gather(bs_ref, bs_buf, bs_sem, nt_bs, bt,
-                       qb.reshape(rb * 2 * e, 1), nrows=1)
-    se = se.reshape(rb, 2 * e)
-    start, end = se[:, :e], se[:, e:]
-    cnt_bucket = end - start
+    bucket = (key & mask_u).astype(i32)                   # (1, E)
+    se = _sweep_gather(bs_ref, bs_buf, bs_sem, nt_bs, bt, bucket)
+    start = se[0:1]
+    cnt_bucket = se[1:2] - start
 
-    idx = (jnp.broadcast_to(start.reshape(rb, e, 1), (rb, e, h))
-           + jax.lax.broadcasted_iota(i32, (rb, e, h), 2)).reshape(rb, eh)
-    idx_c = jnp.minimum(idx, n_entries - 1)
-    ent = _sweep_gather(ent_ref, ent_buf, ent_sem, nt_ent, bt,
-                        idx_c.reshape(rb * eh, 1), nrows=2)
-    word0 = ent[:, 0:1].reshape(rb, eh)
-    t_pos = ent[:, 1:2].reshape(rb, eh)
+    jh = lanes.lane_iota(jnp.zeros((1, eh), i32)) % h      # slot within seed
+    idx = jnp.minimum(_repeat(start, h) + jh, n_entries - 1)
+    ent = _sweep_gather(ent_ref, ent_buf, ent_sem, nt_ent, bt, idx)
+    word0, t_pos = ent[0:1], ent[1:2]                     # (1, E*H)
 
-    # unpack_entries + match_entries, flattened to (RB, E*H)
+    # unpack_entries + match_entries on the flattened (1, E*H) slots
     pu = jax.lax.bitcast_convert_type(word0, jnp.uint32)
-    key_rep = jnp.broadcast_to(
-        key.reshape(rb, e, 1), (rb, e, h)).reshape(rb, eh)
+    key_rep = jax.lax.bitcast_convert_type(
+        _repeat(jax.lax.bitcast_convert_type(key, i32), h), jnp.uint32)
     got_key = (pu & ~mask_u) | (key_rep & mask_u)
     key_cnt = (pu & mask_u).astype(i32)
-    cnt_rep = jnp.broadcast_to(
-        cnt_bucket.reshape(rb, e, 1), (rb, e, h)).reshape(rb, eh)
-    jh = jax.lax.broadcasted_iota(i32, (rb, e, h), 2).reshape(rb, eh)
-    valid_rep = jnp.broadcast_to(
-        seed_valid.reshape(rb, e, 1), (rb, e, h)).reshape(rb, eh)
-    in_bucket = jh < cnt_rep
+    valid_rep = _repeat(seed_valid.astype(i32), h) != 0
+    in_bucket = jh < _repeat(cnt_bucket, h)
     key_match = got_key == key_rep
     raw_hit = in_bucket & key_match & valid_rep
     hit_v = raw_hit & (key_cnt <= thresh_freq) if use_freq else raw_hit
 
-    fm = (key_match & in_bucket).reshape(rb * e, h)
-    first_match = (fm & (_prefix_sum(fm.astype(i32)) == 1)).reshape(rb, eh)
+    fm = (key_match & in_bucket).astype(i32)
+    first_match = (fm == 1) & (lanes.prefix_sum(fm, seg=h) == 1)
 
-    n_seeds = jnp.sum(seed_valid, axis=1, keepdims=True)
+    n_seeds = jnp.sum(seed_valid.astype(i32), axis=1, keepdims=True)
     probes = jnp.sum(jnp.minimum(cnt_bucket, h) * seed_valid,
                      axis=1, keepdims=True)
-    raw = jnp.sum(raw_hit, axis=1, keepdims=True)
-    postfreq = jnp.sum(hit_v, axis=1, keepdims=True)
+    raw = jnp.sum(raw_hit.astype(i32), axis=1, keepdims=True)
+    hit_i = hit_v.astype(i32)
+    postfreq = jnp.sum(hit_i, axis=1, keepdims=True)
     exact = jnp.sum(jnp.where(first_match & valid_rep, key_cnt, 0),
                     axis=1, keepdims=True)
 
-    # ---- vote (vote.vote_filter, per-read histogram partials) -----------
+    # ---- vote (vote.vote_filter: per-read histogram, bin chunk by chunk) -
     if use_vote:
-        q_pos = jax.lax.broadcasted_iota(i32, (rb, e, h), 1).reshape(rb, eh)
+        q_pos = lanes.lane_iota(t_pos) // h
         shifted = (t_pos - q_pos) + DIAG_SHIFT
         clipped = jnp.maximum(shifted, 0)
-        n_clip = jnp.sum(hit_v & (shifted < 0), axis=1, keepdims=True)
+        n_clip = jnp.sum(jnp.where(shifted < 0, hit_i, 0),
+                         axis=1, keepdims=True)
         w1 = (clipped >> vlog2) % nbins
         w2 = ((clipped >> vlog2) + 1) % nbins
-        bins_hn = jax.lax.broadcasted_iota(i32, (eh, nbins), 1)
-        keep_rows = []
-        for r in range(rb):
-            oh1 = (w1[r:r + 1].T == bins_hn).astype(f32)  # (EH, nbins)
-            oh2 = (w2[r:r + 1].T == bins_hn).astype(f32)
-            vf = hit_v[r:r + 1].astype(f32)               # (1, EH)
-            votes = (jax.lax.dot(vf, oh1, precision=_HIGHEST)
-                     + jax.lax.dot(vf, oh2, precision=_HIGHEST)).T
-            v1 = jax.lax.dot(oh1, votes, precision=_HIGHEST)  # (EH, 1)
-            v2 = jax.lax.dot(oh2, votes, precision=_HIGHEST)
-            vmax = jnp.maximum(v1, v2).astype(i32).T      # (1, EH)
-            keep_rows.append(hit_v[r:r + 1] & (vmax >= thresh_vote))
-        keep = keep_rows[0] if rb == 1 else jnp.concatenate(keep_rows, 0)
-        n_cast = 2 * jnp.sum(hit_v, axis=1, keepdims=True)
+        vc = math.gcd(nbins, _VOTE_CHUNK)
+        bins = jax.lax.broadcasted_iota(i32, (vc, eh), 0)
+
+        def chunk(c, acc):
+            v1, v2 = acc
+            m1 = w1 == bins + c * vc                      # (vc, E*H)
+            m2 = w2 == bins + c * vc
+            votes = jnp.sum(jnp.where(m1, hit_i, 0) + jnp.where(m2, hit_i, 0),
+                            axis=1, keepdims=True)        # (vc, 1)
+            return (v1 + jnp.sum(jnp.where(m1, votes, 0), axis=0,
+                                 keepdims=True),
+                    v2 + jnp.sum(jnp.where(m2, votes, 0), axis=0,
+                                 keepdims=True))
+
+        zero = jnp.zeros((1, eh), i32)
+        v1, v2 = jax.lax.fori_loop(0, nbins // vc, chunk, (zero, zero))
+        keep = hit_v & (jnp.maximum(v1, v2) >= thresh_vote)
+        n_cast = 2 * postfreq
     else:
         keep = hit_v
-        n_cast = jnp.zeros((rb, 1), i32)
-        n_clip = jnp.zeros((rb, 1), i32)
-    n_anchors = jnp.sum(keep, axis=1, keepdims=True)
+        n_cast = jnp.zeros((1, 1), i32)
+        n_clip = jnp.zeros((1, 1), i32)
+    n_anchors = jnp.sum(keep.astype(i32), axis=1, keepdims=True)
 
     tpos_ref[...] = t_pos
     hit_ref[...] = keep.astype(i32)
-    cnt_ref[...] = jnp.concatenate(
-        [c.astype(i32) for c in
-         (nev, n_seeds, probes, raw, postfreq, exact,
-          n_cast, n_anchors, n_clip)], axis=1)
+    col = lanes.lane_iota(jnp.zeros((1, len(COUNTER_COLS)), i32))
+    cnt = jnp.zeros((1, len(COUNTER_COLS)), i32)
+    for k, c in enumerate((nev, n_seeds, probes, raw, postfreq, exact,
+                           n_cast, n_anchors, n_clip)):
+        cnt = jnp.where(col == k, c.astype(i32), cnt)
+    cnt_ref[...] = cnt
 
 
 @functools.partial(
@@ -335,26 +263,26 @@ def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
                      "levels", "clip_q", "step_q", "n_buckets", "n_entries",
                      "thresh_freq", "use_freq", "use_vote", "vlog2", "nbins",
                      "thresh_vote", "tile", "interpret"))
-def cheap_fused_fixed(xq, bucket_start, entries_packed, *,
+def cheap_fused_fixed(xq, bucket_bounds, entries_packed, *,
                       n_ev_max, hits, tw, tau2, eps, peak_r, frac_bits,
                       seed_w, seed_q, minimizer_r, levels, clip_q, step_q,
                       n_buckets, n_entries, thresh_freq, use_freq, use_vote,
                       vlog2, nbins, thresh_vote, tile, interpret=None):
-    """Launch the mega-kernel over a padded read block.
+    """Launch the mega-kernel, one read per grid step.
 
-    xq             (Rp, S)     int32, Rp % tile.r_blk == 0
-    bucket_start   (1, NBpad)  int32, NBpad % tile.bt == 0
-    entries_packed (2, Npad)   int32, Npad % tile.bt == 0
-    Returns t_pos (Rp, E*H) i32, hit (Rp, E*H) i32, counters (Rp, 9) i32.
+    xq             (R, S)      int32 Q-format signal
+    bucket_bounds  (2, NBpad)  int32 [start, end] of each bucket
+    entries_packed (2, Npad)   int32, NBpad and Npad % tile.bt == 0
+    Returns t_pos (R, E*H) i32, hit (R, E*H) i32, counters (R, 9) i32.
     """
     if interpret is None:
         interpret = K.INTERPRET
-    rp, s = xq.shape
-    rb, bt = tile.r_blk, tile.bt
-    assert rp % rb == 0 and bucket_start.shape[1] % bt == 0 \
-        and entries_packed.shape[1] % bt == 0
+    r, s = xq.shape
+    bt = tile.bt
+    assert (bucket_bounds.shape[1] % bt == 0
+            and entries_packed.shape[1] % bt == 0)
     eh = n_ev_max * hits
-    grid = (rp // rb,)
+    nc = len(COUNTER_COLS)
     kern = functools.partial(
         _kernel, n_ev_max=n_ev_max, hits=hits, tw=tw, tau2=tau2, eps=eps,
         peak_r=peak_r, frac_bits=frac_bits, seed_w=seed_w, seed_q=seed_q,
@@ -362,34 +290,34 @@ def cheap_fused_fixed(xq, bucket_start, entries_packed, *,
         step_q=step_q, n_buckets=n_buckets, n_entries=n_entries,
         thresh_freq=thresh_freq, use_freq=use_freq, use_vote=use_vote,
         vlog2=vlog2, nbins=nbins, thresh_vote=thresh_vote, bt=bt,
-        nt_bs=bucket_start.shape[1] // bt,
+        nt_bs=bucket_bounds.shape[1] // bt,
         nt_ent=entries_packed.shape[1] // bt)
-    call = pl.pallas_call(
+
+    # (R, 1, X) operands with the read axis squeezed out of each block: the
+    # kernel sees one read's (1, X) row, a legal Mosaic block for any R
+    def row(width):
+        return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0))
+
+    t_pos, hit, cnt = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rb, s), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((rb, eh), lambda i: (i, 0)),
-            pl.BlockSpec((rb, eh), lambda i: (i, 0)),
-            pl.BlockSpec((rb, len(COUNTER_COLS)), lambda i: (i, 0)),
-        ],
+        grid=(r,),
+        in_specs=[row(s),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[row(eh), row(eh), row(nc)],
         out_shape=[
-            jax.ShapeDtypeStruct((rp, eh), jnp.int32),
-            jax.ShapeDtypeStruct((rp, eh), jnp.int32),
-            jax.ShapeDtypeStruct((rp, len(COUNTER_COLS)), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, eh), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, eh), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, nc), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, 1, bt), jnp.int32),
+            pltpu.VMEM((2, 2, bt), jnp.int32),
             pltpu.VMEM((2, 2, bt), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=K.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )
-    return call(xq, bucket_start, entries_packed)
+    )(xq.reshape(r, 1, s), bucket_bounds, entries_packed)
+    return t_pos.reshape(r, eh), hit.reshape(r, eh), cnt.reshape(r, nc)

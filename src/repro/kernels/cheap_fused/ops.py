@@ -1,24 +1,22 @@
 """Public wrapper for the fused cheap-phase mega-kernel.
 
 Host graph: normalize + early-quantize the signals (same split as the
-event_detect wrapper), pad reads to the block grid and the 2-plane packed
-index to the DMA tile width, launch the mega-kernel once, then slice the
-padding back off and rebuild the cheap-phase (q_pos, t_pos, hit_valid,
-counters) contract.
+event_detect wrapper), lay the bucket boundaries out as a (2, NB) table and
+pad both index tables to the DMA tile width, launch the mega-kernel once,
+then rebuild the cheap-phase (q_pos, t_pos, hit_valid, counters)
+contract.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
-import jax
 import jax.numpy as jnp
 
-from repro import kernels as K
 from repro.core import events as ev
 from repro.core import stages
 from repro.core.config import MarsConfig
 from repro.kernels.cheap_fused.cheap_fused import (
-    COUNTER_COLS, FusedTile, cheap_fused_fixed, tune_tile)
+    COUNTER_COLS, DEFAULT_TILE, FusedTile, cheap_fused_fixed)
 
 
 def _pad_axis(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -32,7 +30,7 @@ def _pad_axis(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 
 def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
-                cfg: MarsConfig, tile: Optional[FusedTile] = None):
+                cfg: MarsConfig, tile: FusedTile = DEFAULT_TILE):
     """signals: (R, S) f32 raw; index: the packed online index view.
 
     Returns (q_pos, t_pos, hit_valid, counters) — the exact
@@ -41,22 +39,19 @@ def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
     """
     assert cfg.fixed_point and cfg.early_quantization, (
         "mega-kernel implements the MARS fixed-point path")
-    if tile is None:
-        tile = tune_tile("interpret" if K.INTERPRET
-                         else jax.default_backend())
     x = ev.robust_normalize(signals)
     xq = ev.quantize_signal_fixed(x, cfg.frac_bits).astype(jnp.int32)
     r = xq.shape[0]
     e, h = cfg.max_events, cfg.max_hits_per_seed
 
     n_entries = index["entries_packed"].shape[-1]
-    bs = _pad_axis(index["bucket_start"].reshape(1, -1), 1, tile.bt)
+    bstart = index["bucket_start"]
+    bounds = _pad_axis(jnp.stack([bstart[:-1], bstart[1:]]), 1, tile.bt)
     ent = _pad_axis(index["entries_packed"], 1, tile.bt)
-    xq = _pad_axis(xq, 0, tile.r_blk)
 
     clip_q = int(round(cfg.quant_clip_sigma * (1 << cfg.frac_bits)))
     t_pos, hit, cnt = cheap_fused_fixed(
-        xq, bs, ent,
+        xq, bounds, ent,
         n_ev_max=e, hits=h, tw=cfg.tstat_window,
         tau2=int(round(cfg.tstat_threshold ** 2)),
         eps=1 << (2 * cfg.frac_bits - 8),
@@ -69,9 +64,9 @@ def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
         use_vote=cfg.use_vote_filter, vlog2=cfg.voting_window_log2,
         nbins=cfg.vote_bins, thresh_vote=cfg.thresh_voting, tile=tile)
 
-    t_pos = t_pos[:r].reshape(r, e, h)
-    hit_valid = hit[:r].reshape(r, e, h).astype(bool)
-    counters = {name: cnt[:r, i] for i, name in enumerate(COUNTER_COLS)}
+    t_pos = t_pos.reshape(r, e, h)
+    hit_valid = hit.reshape(r, e, h).astype(bool)
+    counters = {name: cnt[:, i] for i, name in enumerate(COUNTER_COLS)}
     q_pos = jnp.broadcast_to(
         jnp.arange(e, dtype=jnp.int32)[None, :, None], t_pos.shape)
     return q_pos, t_pos, hit_valid, counters

@@ -5,16 +5,17 @@ Implements MARS's fixed-point event-detection stage (paper Sections 5.2 +
 (sqrt-free) t-statistic boundary test and reduced to per-segment means.
 
 TPU mapping of the near-DRAM Arithmetic Unit:
-  * word-serial window sums  -> lane-shifted adds on the VPU (w <= 8 shifts);
+  * word-serial window sums  -> lane-rotated adds on the VPU (w <= 8 shifts);
   * per-sample boundary test -> branch-free integer compare vector;
   * the peak-pick            -> shifted max-accumulation;
   * event-id assignment      -> Hillis-Steele prefix sum (log2 S shift-adds);
   * segment mean reduction   -> one-hot matmul on the MXU:
         sums = x (1,S) @ onehot(eid) (S,E).
 
-Block layout: one read per program — signal (1, S) int32 Q-format in VMEM,
-outputs (1, E) f32 means and (1, 1) int32 event count.  All arithmetic
-matches core/events.py (the pure-jnp oracle) bit-for-bit.
+Block layout: ``lanes.row_block`` reads per program — signal (RB, S) int32
+Q-format in VMEM, outputs (RB, E) f32 means and (RB, 1) int32 event counts.
+``detect_rows`` is the block body, shared with the fused cheap-phase kernel.
+All arithmetic matches core/events.py (the pure-jnp oracle) bit-for-bit.
 """
 from __future__ import annotations
 
@@ -26,44 +27,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import kernels as K
+from repro.kernels import lanes
 
 _NEG = -3.0e38  # python float: jnp scalars would be captured as constants
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _shift_left(x, d, fill):
-    """x: (1, S); returns x[:, i+d] with `fill` past the end (static d)."""
-    if d == 0:
-        return x
-    S = x.shape[1]
-    pad = jnp.full((1, d), fill, x.dtype)
-    return jnp.concatenate([x[:, d:], pad], axis=1)
+def detect_rows(x: jnp.ndarray, *, E: int, w: int, tau2: int, eps: int,
+                peak_r: int, frac_bits: int):
+    """x: (RB, S) int32 Q-format signal rows.
 
-
-def _shift_right(x, d, fill):
-    if d == 0:
-        return x
-    S = x.shape[1]
-    pad = jnp.full((1, d), fill, x.dtype)
-    return jnp.concatenate([pad, x[:, : S - d]], axis=1)
-
-
-def _kernel(xq_ref, means_ref, nev_ref, *, S: int, E: int, w: int,
-            tau2: int, eps: int, peak_r: int, frac_bits: int):
-    x = xq_ref[...].astype(jnp.int32)                   # (1, S)
+    Returns (means (RB, E) f32 normalized units, n_events (RB, 1) int32)."""
+    rb, S = x.shape
 
     # ---- windowed sums (truncated windows at the borders == zero fill) ----
-    zero = jnp.int32(0)
+    xx = x * x
     sum_r = jnp.zeros_like(x)
     sq_r = jnp.zeros_like(x)
     sum_l = jnp.zeros_like(x)
     sq_l = jnp.zeros_like(x)
     for d in range(w):
-        xr = _shift_left(x, d, zero)                    # x[i+d]
-        sum_r = sum_r + xr
-        sq_r = sq_r + xr * xr
-        xl = _shift_right(x, d + 1, zero)               # x[i-1-d]
-        sum_l = sum_l + xl
-        sq_l = sq_l + xl * xl
+        sum_r = sum_r + lanes.shift_left(x, d, 0)        # x[i+d]
+        sq_r = sq_r + lanes.shift_left(xx, d, 0)
+        sum_l = sum_l + lanes.shift_right(x, d + 1, 0)   # x[i-1-d]
+        sq_l = sq_l + lanes.shift_right(xx, d + 1, 0)
 
     # ---- integer boundary test (events.boundary_mask_fixed) ----
     diff = (sum_r - sum_l) >> 2
@@ -77,33 +64,36 @@ def _kernel(xq_ref, means_ref, nev_ref, *, S: int, E: int, w: int,
     # ---- peak pick: windowed max via shifts ----
     wmax = score
     for d in range(1, peak_r + 1):
-        wmax = jnp.maximum(wmax, _shift_left(score, d, _NEG))
-        wmax = jnp.maximum(wmax, _shift_right(score, d, _NEG))
+        wmax = jnp.maximum(wmax, lanes.shift_left(score, d, _NEG))
+        wmax = jnp.maximum(wmax, lanes.shift_right(score, d, _NEG))
     lmax = score
     for d in range(1, peak_r + 1):
-        lmax = jnp.maximum(lmax, _shift_right(score, d, _NEG))
+        lmax = jnp.maximum(lmax, lanes.shift_right(score, d, _NEG))
     boundary = (score >= wmax) & (score >= lmax) & above
 
-    # ---- event ids: inclusive prefix sum (Hillis-Steele) ----
-    eid = boundary.astype(jnp.int32)
-    d = 1
-    while d < S:
-        eid = eid + _shift_right(eid, d, zero)
-        d *= 2
-    n_events = jnp.minimum(eid[0, S - 1] + 1, E)
-    eid = jnp.minimum(eid, E - 1)                       # (1, S)
+    # ---- event ids: inclusive prefix sum (nondecreasing: max == last) ----
+    eid = lanes.prefix_sum(boundary.astype(jnp.int32))
+    n_events = jnp.minimum(jnp.max(eid, axis=1, keepdims=True) + 1, E)
+    eid = jnp.minimum(eid, E - 1)                       # (RB, S)
 
-    # ---- segment means: one-hot matmul on the MXU ----
+    # ---- segment means: one-hot matmul on the MXU, one row at a time ----
     bins = jax.lax.broadcasted_iota(jnp.int32, (S, E), 1)
-    onehot = (eid.reshape(S, 1) == bins).astype(jnp.float32)   # (S, E)
     xf = x.astype(jnp.float32)                          # exact: |x| < 2^12
-    sums = jax.lax.dot(xf, onehot, precision=jax.lax.Precision.HIGHEST)
     ones = jnp.ones((1, S), jnp.float32)
-    cnts = jax.lax.dot(ones, onehot, precision=jax.lax.Precision.HIGHEST)
-    means = sums / jnp.maximum(cnts, 1.0) / float(1 << frac_bits)
+    rows = []
+    for r in range(rb):
+        onehot = (eid[r:r + 1].reshape(S, 1) == bins).astype(jnp.float32)
+        sums = jax.lax.dot(xf[r:r + 1], onehot, precision=_HIGHEST)
+        cnts = jax.lax.dot(ones, onehot, precision=_HIGHEST)
+        rows.append(sums / jnp.maximum(cnts, 1.0) / float(1 << frac_bits))
+    means = rows[0] if rb == 1 else jnp.concatenate(rows, axis=0)
+    return means, n_events
 
-    means_ref[...] = means                              # (1, E)
-    nev_ref[...] = n_events.reshape(1, 1)
+
+def _kernel(xq_ref, means_ref, nev_ref, **params):
+    means, n_events = detect_rows(xq_ref[...], **params)
+    means_ref[...] = means
+    nev_ref[...] = n_events
 
 
 @functools.partial(jax.jit,
@@ -119,22 +109,25 @@ def event_detect_fixed(xq: jnp.ndarray, *, E: int, w: int, tau2: int,
     if interpret is None:
         interpret = K.INTERPRET
     R, S = xq.shape
-    kern = functools.partial(_kernel, S=S, E=E, w=w, tau2=tau2, eps=eps,
+    rb = lanes.row_block(R)
+    xp = lanes.pad_rows(xq.astype(jnp.int32), rb)
+    rp = xp.shape[0]
+    kern = functools.partial(_kernel, E=E, w=w, tau2=tau2, eps=eps,
                              peak_r=peak_r, frac_bits=frac_bits)
     means, nev = pl.pallas_call(
         kern,
-        grid=(R,),
-        in_specs=[pl.BlockSpec((1, S), lambda r: (r, 0))],
+        grid=(rp // rb,),
+        in_specs=[pl.BlockSpec((rb, S), lambda r: (r, 0))],
         out_specs=[
-            pl.BlockSpec((1, E), lambda r: (r, 0)),
-            pl.BlockSpec((1, 1), lambda r: (r, 0)),
+            pl.BlockSpec((rb, E), lambda r: (r, 0)),
+            pl.BlockSpec((rb, 1), lambda r: (r, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((R, E), jnp.float32),
-            jax.ShapeDtypeStruct((R, 1), jnp.int32),
+            jax.ShapeDtypeStruct((rp, E), jnp.float32),
+            jax.ShapeDtypeStruct((rp, 1), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=K.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-    )(xq.astype(jnp.int32))
-    return means, nev.reshape(R)
+    )(xp)
+    return means[:R], nev[:R].reshape(R)

@@ -104,7 +104,7 @@ def pluto_lookup_rows(table: jnp.ndarray, idx: jnp.ndarray,
         out_specs=pl.BlockSpec((W, BQ), lambda qi, ti: (0, qi)),
         out_shape=jax.ShapeDtypeStruct((W, Q), jnp.int32),
         interpret=interpret,
-        compiler_params=K.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(idx.reshape(1, Q), table)
     return out
@@ -132,7 +132,7 @@ def pluto_lookup(table: jnp.ndarray, idx: jnp.ndarray,
         out_specs=pl.BlockSpec((1, BQ), lambda qi, ti: (0, qi)),
         out_shape=jax.ShapeDtypeStruct((1, Q), jnp.int32),
         interpret=interpret,
-        compiler_params=K.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(idx.reshape(1, Q), table.reshape(1, N))
     return out.reshape(Q)

@@ -17,8 +17,10 @@ import time
 
 import numpy as np
 
-from repro.core import MarsConfig, Mapper, build_index, driver, score_accuracy
-from repro.signal import datasets, reader, simulate
+from repro.core import (Mapper, build_index, driver, score_accuracy,
+                        stages)
+from repro.launch import compile_cache
+from repro.signal import datasets, reader
 
 
 def main(argv=None):
@@ -32,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--reads", type=int, default=None)
     ap.add_argument("--use-kernels", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     spec = datasets.DATASETS[args.dataset]
     cfg = datasets.config_for(spec).with_mode(args.mode)
@@ -41,10 +44,8 @@ def main(argv=None):
     # ---- build (or reuse) reference/index/reads --------------------------- #
     sig_file = wd / f"{spec.key}_signals.mars"
     t0 = time.time()
-    ref = simulate.make_reference(spec.genome_len, seed=spec.seed)
     n_reads = args.reads or spec.bench_reads
-    rs = simulate.sample_reads(ref, n_reads, signal_len=cfg.signal_len,
-                               seed=spec.seed + 1, junk_frac=0.08)
+    ref, rs = datasets.build(spec, cfg, n_reads)
     reader.write_signals(sig_file, rs.signals)
     index = build_index(ref.events_concat, ref.n_events, cfg)
     print(f"[setup] genome={spec.genome_len}bp reads={n_reads} "
@@ -58,6 +59,9 @@ def main(argv=None):
         print(f"[resume] continuing at chunk {start_chunk}")
 
     mapper = Mapper(index, cfg, use_kernels=args.use_kernels)
+    # the resolved plan, so a stage that fell back to the reference shows
+    fused = stages.fused_cheap_backend(mapper.plan, cfg) is not None
+    print(f"[plan] {dict(mapper.plan)} fused_cheap={fused}")
     rdr = reader.SignalReader(sig_file, chunk=args.chunk,
                               start_chunk=start_chunk)
     t0 = time.time()

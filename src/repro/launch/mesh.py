@@ -11,6 +11,16 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis Auto: sharding follows the
+    arguments' NamedShardings and ``with_sharding_constraint``, as the
+    sharding rules in ``distributed/sharding.py`` expect.  (Without
+    ``axis_types``, JAX >= 0.9 makes Explicit axes.)"""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, layout: str = "2d"):
@@ -22,16 +32,16 @@ def make_production_mesh(*, multi_pod: bool = False, layout: str = "2d"):
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = (("pod", "data", "data2") if multi_pod
                 else ("data", "data2"))
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh for tests / elastic restarts (e.g. (4,2) on 8 CPU
     devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

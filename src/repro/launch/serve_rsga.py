@@ -36,7 +36,8 @@ import numpy as np
 from repro.core import (FaultPlan, MarsConfig, Mapper, ServeDriver, SLOClass,
                         TenantBudget, build_index, costmodel, ssd_model,
                         workload)
-from repro.signal import datasets, simulate
+from repro.launch import compile_cache
+from repro.signal import datasets
 
 
 def build_trace(signals: np.ndarray, n_streams: int, reads_per_stream: int,
@@ -156,14 +157,13 @@ def main(argv=None):
                          "and print the shed-rate vs p50/p99 curve")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     spec = datasets.DATASETS[args.dataset]
     cfg = datasets.config_for(spec).with_mode(args.mode)
     t0 = time.time()
-    ref = simulate.make_reference(spec.genome_len, seed=spec.seed)
     n_reads = args.streams * args.reads_per_stream
-    rs = simulate.sample_reads(ref, n_reads, signal_len=cfg.signal_len,
-                               seed=spec.seed + 1, junk_frac=0.08)
+    ref, rs = datasets.build(spec, cfg, n_reads)
     index = build_index(ref.events_concat, ref.n_events, cfg)
     print(f"[setup] genome={spec.genome_len}bp streams={args.streams} "
           f"reads/stream={args.reads_per_stream} "

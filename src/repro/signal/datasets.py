@@ -70,9 +70,12 @@ def config_for(spec: DatasetSpec, base: MarsConfig = MarsConfig()) -> MarsConfig
     return base.replace(thresh_freq=12, thresh_voting=4)
 
 
-def build(spec: DatasetSpec, cfg: MarsConfig, signal_len: int = 1024):
+def build(spec: DatasetSpec, cfg: MarsConfig, n_reads: int = None):
+    """The dataset's seeded reference and ``n_reads`` simulated reads of
+    ``cfg.signal_len`` samples (default ``spec.bench_reads``) — the data
+    every launcher maps."""
     ref = simulate.make_reference(spec.genome_len, seed=spec.seed)
-    reads = simulate.sample_reads(ref, spec.bench_reads,
-                                  signal_len=signal_len,
+    reads = simulate.sample_reads(ref, n_reads or spec.bench_reads,
+                                  signal_len=cfg.signal_len,
                                   seed=spec.seed + 1, junk_frac=0.08)
     return ref, reads

@@ -74,12 +74,12 @@ def test_fused_matches_per_stage_and_vmap(setup):
 
 
 @pytest.mark.parametrize("n_reads,tile", [
-    (1, FusedTile(r_blk=1, bt=512)),
-    (3, FusedTile(r_blk=2, bt=128)),    # row padding: 3 reads, blocks of 2
-    (5, FusedTile(r_blk=3, bt=64)),     # 5 reads, blocks of 3
+    (1, FusedTile(bt=512)),
+    (3, FusedTile(bt=128)),
+    (5, FusedTile(bt=64)),
 ])
 def test_fused_odd_shapes_and_tiles(setup, n_reads, tile):
-    """Read counts that do not divide the row block + small DMA tiles that
+    """Odd read counts (one read per grid step) + small DMA tiles that
     force many partial index sweeps must stay bit-exact."""
     cfg, signals, arrays = setup
     got = cheap_fused(signals[:n_reads], arrays, cfg, tile=tile)
@@ -100,7 +100,7 @@ def test_fused_index_tile_boundary_probes(setup):
     multi-thousand-entry index guarantees straddling probes."""
     cfg, signals, arrays = setup
     plan = stages.resolve_plan(cfg, stages.PALLAS)
-    got = cheap_fused(signals, arrays, cfg, tile=FusedTile(r_blk=2, bt=32))
+    got = cheap_fused(signals, arrays, cfg, tile=FusedTile(bt=32))
     want = pipeline.cheap_phase(signals, arrays, cfg, plan, use_fused=False)
     _assert_cheap_equal(got, want)
 

@@ -114,7 +114,7 @@ def test_int8_psum_collective():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_mesh
 from repro.distributed.collectives import psum_int8
 mesh = make_mesh((8,), ("data",))
