@@ -27,6 +27,7 @@ import pathlib
 from typing import Callable, Dict, Iterable, Iterator, List, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # (chunk_idx, n_valid, padded signals (chunk, S) f32)
 Chunk = Tuple[int, int, np.ndarray]
@@ -54,8 +55,6 @@ def array_chunks(signals: np.ndarray, chunk: int,
 def stream_map(map_fn: Callable[[np.ndarray, int], "MapOutput"],
                chunks: Iterable[Chunk],
                prefetch: Callable[[np.ndarray, int], None] = None,
-               trace: list = None,
-               clock: Callable[[], float] = None,
                ) -> Iterator[Tuple[int, int, "MapOutput"]]:
     """Double-buffered device loop.
 
@@ -73,38 +72,26 @@ def stream_map(map_fn: Callable[[np.ndarray, int], "MapOutput"],
     is unchanged — live chunk sources (the serving driver's ready queue)
     depend on the exact pull timing.
 
-    With ``trace`` (a list) the loop appends the replayable chunk-event
-    records ``("dispatch", t, ci, n_valid)`` at async dispatch and
-    ``("complete", t, ci, n_valid)`` when the chunk's results reach the
-    host — the batch-side half of the serving trace format
-    (core/sim/serve_sim.py; ``ServeDriver`` records its richer
-    virtual-time trace itself).  ``t`` comes from ``clock()`` when given
-    (e.g. a virtual clock), else it counts dispatches.  Recording is pure
-    observation: pull order and outputs are unchanged.
+    Each chunk opens two profiler spans: ``driver.dispatch`` around
+    ``map_fn`` (the host-to-device copy and the jit launch) and
+    ``driver.fetch`` around the pull of its results (waiting for the
+    device, then the transfers to the host), closed before the yield.
 
     A ``prefetch`` exception does NOT abandon the chunk already in flight
     on the device: the loop stops reading ahead, drains every dispatched
     chunk through the iterator, and re-raises the failure once at the end
     of the stream.
     """
-    n_seen = 0
-
-    def _note(kind: str, ci: int, n_valid: int) -> None:
-        if trace is not None:
-            trace.append((kind, clock() if clock is not None
-                          else float(n_seen), ci, n_valid))
-
     def _emit(p):
-        _note("complete", p[0], p[1])
-        return _to_host(*p)
+        with TraceAnnotation("driver.fetch"):
+            return _to_host(*p)
 
     pending = None
     exc = None
     if prefetch is None:
         for ci, n_valid, sig in chunks:
-            out = map_fn(sig, n_valid)      # async dispatch
-            n_seen += 1
-            _note("dispatch", ci, n_valid)
+            with TraceAnnotation("driver.dispatch"):
+                out = map_fn(sig, n_valid)  # async dispatch
             if pending is not None:
                 yield _emit(pending)
             pending = (ci, n_valid, out)
@@ -118,9 +105,8 @@ def stream_map(map_fn: Callable[[np.ndarray, int], "MapOutput"],
                 exc, nxt = e, None
         while nxt is not None:
             ci, n_valid, sig = nxt
-            out = map_fn(sig, n_valid)      # async dispatch
-            n_seen += 1
-            _note("dispatch", ci, n_valid)
+            with TraceAnnotation("driver.dispatch"):
+                out = map_fn(sig, n_valid)  # async dispatch
             nxt = next(it, None)
             if nxt is not None:
                 try:

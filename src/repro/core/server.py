@@ -50,8 +50,11 @@ arrival traces, deadlines and per-read latency accounting — every
 dispatched chunk advances it by ``chunk_cost`` scaled by the prefix
 fraction, and virtual time the tiered storage path loses to page-in
 retry/backoff (``HotTileCache.vtime_penalty``) is folded in as it
-accrues.  Wall-clock throughput is measured separately by the caller
-(benchmarks/microbench.py, launch/serve_rsga.py).
+accrues.  Wall-clock time is read from the profiler's trace: each chunk
+opens a ``serve.pack`` span while it is packed and a ``serve.route`` span
+while its results are routed, between ``driver.stream_map``'s
+``driver.dispatch`` and ``driver.fetch`` spans.  Chunks route in packing
+order, so the k-th span of each kind belongs to the k-th chunk.
 
 Overload (the closed loop): with ``shed=True`` the driver feeds its
 overload evidence into the configured ``CostModel``
@@ -96,6 +99,7 @@ import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import costmodel, driver, ssd_model
 
@@ -631,38 +635,40 @@ class ServeDriver:
                 self._reject(s)
         if not self._queue:
             return None
-        self._queue.sort(key=_Slot.rank)
-        stage = self._queue[0].stage
-        if (self.shed and self.early_term and len(self.stages) > 1
-                and self._saturated()):
-            # early-term-first degradation: under overload pack the
-            # SHORTEST prefix stage present — the cheapest chunk, with the
-            # best odds of resolving reads early and freeing slots
-            stage = min(s.stage for s in self._queue)
-        take, rest = [], []
-        for s in self._queue:
-            (take if (s.stage == stage and len(take) < self.chunk)
-             else rest).append(s)
-        self._queue = rest
-        L = self.stages[stage]
-        part = np.stack([s.signal[:L] for s in take])
-        ci = self.n_chunks
-        self.n_chunks += 1
-        self.n_pad_rows += self.chunk - len(take)
-        # measured queue delay: how long each packed read waited between
-        # admission and this dispatch (pre-advance clock) — the shed
-        # controller's capacity-loss evidence
-        for s in take:
-            self._queue_delays.append(self.clock - s.t_arrive)
-        self.events.append(("dispatch", self.clock, ci, stage, len(take),
-                            L / self.stages[-1]))
-        self.clock += self.chunk_cost * L / self.stages[-1]
-        # completion time is fixed at dispatch: stream_map's double buffer
-        # routes chunk i only after pulling chunk i+1, so reading the live
-        # clock at routing time would overcharge every chunk but the last
-        self._inflight[ci] = (stage, take, self.clock)
-        self._stage_fifo.append(stage)
-        return ci, len(take), driver.pad_rows(part, self.chunk)
+        with TraceAnnotation("serve.pack"):
+            self._queue.sort(key=_Slot.rank)
+            stage = self._queue[0].stage
+            if (self.shed and self.early_term and len(self.stages) > 1
+                    and self._saturated()):
+                # early-term-first degradation: under overload pack the
+                # SHORTEST prefix stage present — the cheapest chunk, with
+                # the best odds of resolving reads early and freeing slots
+                stage = min(s.stage for s in self._queue)
+            take, rest = [], []
+            for s in self._queue:
+                (take if (s.stage == stage and len(take) < self.chunk)
+                 else rest).append(s)
+            self._queue = rest
+            L = self.stages[stage]
+            part = np.stack([s.signal[:L] for s in take])
+            ci = self.n_chunks
+            self.n_chunks += 1
+            self.n_pad_rows += self.chunk - len(take)
+            # measured queue delay: how long each packed read waited
+            # between admission and this dispatch (pre-advance clock) — the
+            # shed controller's capacity-loss evidence
+            for s in take:
+                self._queue_delays.append(self.clock - s.t_arrive)
+            self.events.append(("dispatch", self.clock, ci, stage,
+                                len(take), L / self.stages[-1]))
+            self.clock += self.chunk_cost * L / self.stages[-1]
+            # completion time is fixed at dispatch: stream_map's double
+            # buffer routes chunk i only after pulling chunk i+1, so reading
+            # the live clock at routing time would overcharge every chunk
+            # but the last
+            self._inflight[ci] = (stage, take, self.clock)
+            self._stage_fifo.append(stage)
+            return ci, len(take), driver.pad_rows(part, self.chunk)
 
     def _chunk_source(self) -> Iterable[driver.Chunk]:
         while True:
@@ -756,7 +762,8 @@ class ServeDriver:
                 continue
             for ci, n_valid, out in driver.stream_map(self._map_fn,
                                                       self._chunk_source()):
-                self._route(ci, n_valid, out)
+                with TraceAnnotation("serve.route"):
+                    self._route(ci, n_valid, out)
 
     def serve_trace(self, trace: Iterable[Tuple]) -> Dict[str, StreamReport]:
         """Run an arrival trace to completion.

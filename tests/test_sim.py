@@ -24,7 +24,7 @@ import math
 import numpy as np
 import pytest
 
-from repro.core import Mapper, ServeDriver, costmodel, driver, ssd_model
+from repro.core import Mapper, ServeDriver, costmodel, ssd_model
 from repro.core.sim import (replay_chunk_trace, simulate_array_latency,
                             simulate_batch, simulate_dram_sensitivity,
                             simulate_serving, simulate_serving_virtual)
@@ -186,25 +186,6 @@ def test_serve_trace_replays_exactly(small_index, cfg_fixed, small_reads):
     assert rep["n_reads_arrived"] == small_reads.signals.shape[0]
     assert rep["makespan"] == pytest.approx(sd.clock)
     assert 0.0 < rep["dispatch_busy"] <= 1.0
-
-
-def test_stream_map_records_trace(small_index, cfg_fixed, small_reads):
-    mapper = Mapper(small_index, cfg_fixed)
-    trace = []
-    stream = driver.stream_map(mapper.chunk_fn(),
-                               driver.array_chunks(small_reads.signals, 4),
-                               trace=trace)
-    n = sum(1 for _ in stream)
-    kinds = [k for k, _, _, _ in trace]
-    assert kinds.count("dispatch") == n and kinds.count("complete") == n
-    # observation only: a trace-free run yields identical outputs
-    want = mapper.map_signals(small_reads.signals, chunk=4)
-    got = driver.collect(driver.stream_map(
-        mapper.chunk_fn(), driver.array_chunks(small_reads.signals, 4),
-        trace=[]))
-    np.testing.assert_array_equal(np.asarray(want.mapped),
-                                  np.asarray(got.mapped))
-    assert want.counters == got.counters
 
 
 # --------------------------------------------------------------------------- #
