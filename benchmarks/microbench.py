@@ -34,7 +34,7 @@ split by stage group:
                   plus the cache's hit-rate / paged-bytes telemetry
     fused         the whole-phase mega-kernel group (top-level ``fused``
                   key): the cheap phase through kernels/cheap_fused (ONE
-                  kernel launch, DMA-streamed index tiles) vs the same
+                  kernel launch, probed index rows gathered) vs the same
                   pallas plan's per-stage program
                   (``pipeline.cheap_phase(use_fused=False)``)
     fairness      the multi-tenant fair-serving group (top-level
@@ -602,8 +602,8 @@ def bench_cache_ratio(cfg: MarsConfig, signals, arrays,
 
 def _fused_programs(cfg: MarsConfig, signals, arrays):
     """(fast_call, pre_call): the whole-phase fused mega-kernel
-    (kernels/cheap_fused — ONE launch, detect..vote resident, index tiles
-    DMA-streamed through scratch) vs the SAME pallas plan's per-stage
+    (kernels/cheap_fused — ONE launch, detect..vote resident, probed index
+    rows gathered from VMEM) vs the SAME pallas plan's per-stage
     batch program (``pipeline.cheap_phase(use_fused=False)``: separate
     detect kernel, pLUTo gathers and segment-sum vote with every
     intermediate materialized between launches).  Outputs are bit-identical

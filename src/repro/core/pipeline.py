@@ -87,8 +87,9 @@ def cheap_phase(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
 
     Dispatch ladder, most-fused first: (1) the whole-phase mega-kernel
     (``stages.register_fused_cheap``) when the plan's cheap stages match one
-    — detect..vote in ONE kernel launch, index tiles DMA-streamed through
-    scratch (kernels/cheap_fused); (2) the per-stage batch level below;
+    — detect..vote in ONE kernel launch, the probed index rows gathered
+    from VMEM (kernels/cheap_fused), for an index whose tables fit there;
+    (2) the per-stage batch level below;
     (3) ``cheap_phase_vmap``.  ``use_fused=False`` pins level (2) — the
     fused-vs-per-stage microbenchmark pair and parity tests use it.
 
@@ -106,7 +107,9 @@ def cheap_phase(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
         return cheap_phase_vmap(signals, index, cfg, plan)
 
     if use_fused and prims.fused is not None and "t_pre_keys" not in index:
-        return prims.fused(signals, index)
+        fused = prims.fused(signals, index)
+        if fused is not None:
+            return fused
 
     if "t_pre_keys" in index:
         # the tiered traffic pre-pass already ran the plan's own

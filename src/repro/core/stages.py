@@ -315,9 +315,10 @@ class FusedCheapBackend:
 
     fn(signals (R,S), index, cfg) -> (q_pos, t_pos, hit_valid, counters) —
     the exact ``pipeline.cheap_phase`` contract, produced by ONE kernel
-    launch instead of per-stage programs.  ``supports`` gates configs the
-    kernel cannot serve; unsupported configs silently resolve to the
-    per-stage plan (pipeline.cheap_phase's existing dispatch ladder).
+    launch instead of per-stage programs — or None for an index the kernel
+    cannot hold.  ``supports`` gates configs the kernel cannot serve;
+    unsupported configs and declined indexes resolve to the per-stage plan
+    (pipeline.cheap_phase's existing dispatch ladder).
     """
     name: str
     fn: Callable

@@ -4,21 +4,25 @@ One `pl.pallas_call` executes the whole cheap phase for one read per grid
 step without leaving the kernel.  The quantized signal row is staged into
 VMEM by the grid pipeline; event means, quantized symbols and seed keys
 live in registers/scratch instead of round-tripping through HBM between
-stage launches; and the two index tables stay in `pl.ANY` memory and are
-streamed tile-by-tile through VMEM scratch with double-buffered
-`pltpu.make_async_copy` DMA — the `emit_pipeline` idiom spelled out by
-hand: while tile t is being probed (one-hot matmul gather, split into exact
-hi/lo 16-bit f32 planes), the DMA for tile t+1 is already in flight.  This
-mirrors the HotTileCache's host->device prefetch one level down, and
-MARS's flash-load/compute overlap one level up.
+stage launches.
 
-The two tables are (2, N) int32 row pairs, so each is ONE sweep: the
-bucket table holds [bucket_start[b], bucket_start[b+1]] (both boundaries
-of bucket b) and the entry table is the packed entry plane
-[key|cnt, t_pos].  A read's queries lie on the lane axis — E buckets, then
-E*H entry slots — and each probe is a (4, bt) @ (bt, Q) matmul against the
-transposed one-hot, so no value ever changes layout between lanes and
-sublanes.
+The query gathers what the read probes, not the whole index: E bucket
+bound pairs and two table rows a seed, selected by one-hot over the
+table's rows (1/128 of its entries).  Both index tables stay in VMEM
+for the whole launch (a full block with a constant index map, fetched
+once), laid out by `index_planes` as byte planes of 128-entry rows, each
+row one column of a (planes * 128, n_rows) bf16 matrix.  A lookup is two
+levels: a one-hot matmul over the rows brings each query's row onto its
+lane (exact: one 0..255 byte times 1 per output, accumulated in f32),
+then an iota mask over the row's 128 sublanes picks the entry.  The
+bucket bounds [bucket_start[b], bucket_start[b+1]] are one row lookup per
+seed; a seed's H entry rows lie in rows start // 128 and the next, so
+each seed gathers those two rows, shifts them up by start % 128 and
+spreads the first H onto its H anchor slots.  Slots past the bucket read
+the same rows as ``min(start + j, n_entries - 1)``: the entry table is
+padded past its end with copies of its last row.  Reads' queries stay on
+the lane axis (E buckets, then E*H entry slots), so no value changes
+layout between lanes and sublanes.
 
 The math is copied operation-for-operation from the per-stage path so the
 fusion is bit-identical:
@@ -31,12 +35,9 @@ fusion is bit-identical:
 
 The vote histogram is built ``_VOTE_CHUNK`` bins at a time with integer
 lane/sublane reductions, so no (E*H, vote_bins) one-hot is ever whole.
-Tile width is `DEFAULT_TILE`, sized for scoped VMEM on Mosaic; tests
-pass smaller tiles.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 
@@ -51,8 +52,9 @@ from repro.core.vote import DIAG_SHIFT
 from repro.kernels import lanes
 from repro.kernels.event_detect.event_detect import detect_rows
 
-_HIGHEST = jax.lax.Precision.HIGHEST
 _VOTE_CHUNK = 256       # vote bins per histogram pass
+_ROW = 128              # index entries per table row: one lane tile
+_BYTE = 8
 
 # Column order of the fused kernel's per-read counter plane.
 COUNTER_COLS = (
@@ -62,78 +64,100 @@ COUNTER_COLS = (
 )
 
 
-@dataclasses.dataclass(frozen=True)
-class FusedTile:
-    """Block-shape choice for the mega-kernel.
-
-    bt — index-tile width in entries for the double-buffered DMA sweeps;
-    the entry sweep's one-hot is (bt, E*H) f32 in VMEM.
-    """
-    bt: int
-
-
-# One geometry for Mosaic and interpret mode: on Mosaic the entry sweep's
-# (bt, E*H) one-hot and its compare stay within scoped VMEM at D1 widths
-# (E*H = 3072); in interpret mode the CPU parity suite still walks the
-# multi-tile and partial-tail paths.
-DEFAULT_TILE = FusedTile(bt=512)
-
-
 def _repeat(x, h):
     """(1, E) -> (1, E*h): each lane repeated h times, in order."""
     e = x.shape[1]
     return jnp.broadcast_to(x.reshape(1, e, 1), (1, e, h)).reshape(1, e * h)
 
 
-def _sweep_gather(src_ref, buf, sem, n_tiles, bt, q_row):
-    """Double-buffered DMA sweep-gather over a (2, n_tiles*bt) table.
-
-    Streams the table tile-by-tile from `pl.ANY` memory into the 2-slot
-    VMEM scratch `buf`, starting the copy of tile t+1 before probing tile
-    t (hand-rolled `pltpu.emit_pipeline`).  Each tile is probed with a
-    one-hot f32 matmul gather, exact because the int32 values are split
-    into hi/lo 16-bit planes (<= 2^16 in f32) and out-of-tile queries
-    contribute zero columns.
-
-    q_row: (1, Q) int32 global column indices (pre-clipped in range).
-    Returns (2, Q) int32: both table words of every queried column.
-    """
-    q = q_row.shape[1]
-
-    def dma(slot, t):
-        return pltpu.make_async_copy(
-            src_ref.at[:, pl.ds(t * bt, bt)], buf.at[slot], sem.at[slot])
-
-    dma(0, 0).start()
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bt, q), 0)
-
-    def body(t, acc):
-        slot = jax.lax.rem(t, 2)
-
-        @pl.when(t + 1 < n_tiles)
-        def _():
-            dma(1 - slot, t + 1).start()
-
-        dma(slot, t).wait()
-        tab = buf[slot]                                   # (2, bt) int32
-        onehot = (q_row - t * bt == rows).astype(jnp.float32)   # (bt, Q)
-        planes = jnp.concatenate(
-            [jnp.right_shift(tab, 16).astype(jnp.float32),
-             jnp.bitwise_and(tab, 0xFFFF).astype(jnp.float32)], axis=0)
-        return acc + jax.lax.dot(planes, onehot, precision=_HIGHEST)
-
-    acc = jax.lax.fori_loop(0, n_tiles, body,
-                            jnp.zeros((4, q), jnp.float32))
-    return (jnp.left_shift(acc[0:2].astype(jnp.int32), 16)
-            | acc[2:4].astype(jnp.int32))
+def _n_bytes(v: int) -> int:
+    """Byte planes that hold every value in [0, v]."""
+    return max(1, -(-v.bit_length() // _BYTE))
 
 
-def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
-            bs_buf, ent_buf, bs_sem, ent_sem, *,
+def _byte_planes(words, n_bytes):
+    """(W, n) int32 table, n % _ROW == 0 -> (W * n_bytes * _ROW, n_rows)
+    bf16: byte p of word w of entry r * _ROW + l sits at row
+    (w * n_bytes + p) * _ROW + l, column r (n_rows padded to a lane
+    multiple; pad columns are never selected)."""
+    w, n = words.shape
+    shifts = _BYTE * jnp.arange(n_bytes, dtype=jnp.int32).reshape(1, -1, 1, 1)
+    x = (words.reshape(w, 1, n // _ROW, _ROW) >> shifts) & 0xFF
+    x = x.transpose(0, 1, 3, 2).reshape(w * n_bytes * _ROW, n // _ROW)
+    return jnp.pad(x, ((0, 0), (0, -x.shape[1] % _ROW))).astype(jnp.bfloat16)
+
+
+def index_planes(bucket_start, entries_packed):
+    """The kernel's layout of the packed index: (bucket_planes,
+    entry_planes).  Bucket bounds [start, end] hold values <= n_entries,
+    so they take only the bytes n_entries needs; entry words take four.
+    The entry table is padded with copies of its last row far enough that
+    the two rows of every seed's window exist and every slot past the
+    table reads entry n_entries - 1."""
+    n = entries_packed.shape[1]
+    bounds = jnp.stack([bucket_start[:-1], bucket_start[1:]])
+    bounds = jnp.pad(bounds, ((0, 0), (0, -bounds.shape[1] % _ROW)))
+    ent = jnp.pad(entries_packed, ((0, 0), (0, (n // _ROW + 2) * _ROW - n)),
+                  mode="edge")
+    return _byte_planes(bounds, _n_bytes(n)), _byte_planes(ent, 4)
+
+
+def _columns(planes, col):
+    """planes (K, C) bf16, col (1, Q) int32 in [0, C) -> (K, Q) f32:
+    column col[q] of planes, by an exact one-hot matmul."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (planes.shape[1], col.shape[1]),
+                                    0)
+    onehot = (iota == col).astype(jnp.bfloat16)
+    return jax.lax.dot(planes, onehot, preferred_element_type=jnp.float32)
+
+
+def _blocks(x, rows):
+    """Split axis 0 into blocks of ``rows``."""
+    return [x[k:k + rows] for k in range(0, x.shape[0], rows)]
+
+
+def _pick(block, on):
+    """Per column, the one row of ``block`` where ``on`` holds (1, Q)."""
+    return jnp.sum(jnp.where(on, block, 0), axis=0, keepdims=True)
+
+
+def _word(planes):
+    """Byte planes, least significant first (f32 or int32) -> int32."""
+    return functools.reduce(jnp.bitwise_or, (
+        p.astype(jnp.int32) << (_BYTE * k) for k, p in enumerate(planes)))
+
+
+def _bytes(word):
+    """int32 -> its four byte planes, least significant first."""
+    return [(word >> (_BYTE * k)) & 0xFF for k in range(4)]
+
+
+def _shift_up(win, off):
+    """(L, Q) int32, off (1, Q) in [0, _ROW) -> rows win[i + off[q], q] for
+    i + off[q] < L (a per-lane shift in log2(_ROW) static sublane rolls)."""
+    n = win.shape[0]
+    for k in range(_ROW.bit_length() - 1):
+        rolled = pltpu.roll(win, n - (1 << k), 0)        # win[i + 2^k]
+        win = jnp.where(((off >> k) & 1) == 1, rolled, win)
+    return win
+
+
+def _spread(planes, h):
+    """(K, E) bf16 -> (K, E*h) f32 with column e*h + j equal to column e
+    (each column repeated h times, by an exact one-hot matmul)."""
+    e = planes.shape[1]
+    shape = (e, e * h)
+    rep = (jax.lax.broadcasted_iota(jnp.int32, shape, 1) // h
+           == jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    return jax.lax.dot(planes, rep.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+
+
+def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref, *,
             n_ev_max, hits, tw, tau2, eps, peak_r, frac_bits,
             seed_w, seed_q, minimizer_r, levels, clip_q, step_q,
-            n_buckets, n_entries, thresh_freq, use_freq, use_vote,
-            vlog2, nbins, thresh_vote, bt, nt_bs, nt_ent):
+            n_buckets, bound_bytes, thresh_freq, use_freq, use_vote,
+            vlog2, nbins, thresh_vote):
     e, h = n_ev_max, hits
     eh = e * h
     i32 = jnp.int32
@@ -177,17 +201,30 @@ def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
             wmin = jnp.minimum(wmin, lanes.shift_right(kv, d, (1 << 31) - 1))
         seed_valid = seed_valid & (kv == wmin)
 
-    # ---- query (seeding.query_index on the two streamed tables) ----------
+    # ---- query (seeding.query_index: gather the probed rows) -------------
     mask_u = jnp.uint32(n_buckets - 1)
     bucket = (key & mask_u).astype(i32)                   # (1, E)
-    se = _sweep_gather(bs_ref, bs_buf, bs_sem, nt_bs, bt, bucket)
-    start = se[0:1]
-    cnt_bucket = se[1:2] - start
+    # bucket bounds: the bucket's table row by matmul, then its lane
+    cols = _blocks(_columns(bs_ref[...], bucket // _ROW), _ROW)
+    at = jax.lax.broadcasted_iota(i32, (_ROW, e), 0) == bucket % _ROW
+    start, end = (_word([_pick(c, at) for c in cols[w:w + bound_bytes]])
+                  for w in (0, bound_bytes))
+    cnt_bucket = end - start
 
+    # a seed's H entries lie in rows start // _ROW and the next: shift the
+    # two rows up by start % _ROW, keep H, and spread seed e's H entries
+    # onto its slots e*H .. e*H + H-1, byte plane by byte plane
+    ent = ent_ref[...]
+    lo = _blocks(_columns(ent, start // _ROW), _ROW)
+    hi = _blocks(_columns(ent, start // _ROW + 1), _ROW)
+    win = [_shift_up(jnp.concatenate([_word(lo[w:w + 4]), _word(hi[w:w + 4])]),
+                     start % _ROW)[:h] for w in (0, 4)]   # 2 x (H, E)
+    spread = _blocks(_spread(jnp.concatenate(
+        [b for w in win for b in _bytes(w)]).astype(jnp.bfloat16), h), h)
     jh = lanes.lane_iota(jnp.zeros((1, eh), i32)) % h      # slot within seed
-    idx = jnp.minimum(_repeat(start, h) + jh, n_entries - 1)
-    ent = _sweep_gather(ent_ref, ent_buf, ent_sem, nt_ent, bt, idx)
-    word0, t_pos = ent[0:1], ent[1:2]                     # (1, E*H)
+    at = jax.lax.broadcasted_iota(i32, (h, eh), 0) == jh
+    word0, t_pos = (_word([_pick(c, at) for c in spread[w:w + 4]])
+                    for w in (0, 4))                      # (1, E*H)
 
     # unpack_entries + match_entries on the flattened (1, E*H) slots
     pu = jax.lax.bitcast_convert_type(word0, jnp.uint32)
@@ -256,68 +293,79 @@ def _kernel(xq_ref, bs_ref, ent_ref, tpos_ref, hit_ref, cnt_ref,
     cnt_ref[...] = cnt
 
 
+def table_bytes(*tables):
+    return sum(t.size * t.dtype.itemsize for t in tables)
+
+
+def _vmem_bytes(*tables):
+    """Scoped VMEM for the launch: the resident tables, room as large again
+    for the one-hots over their rows, and the rest of the kernel's working
+    set (under 3 MB at D1 widths)."""
+    return 2 * table_bytes(*tables) + (16 << 20)
+
+
+# Largest tables the launch can hold within a TPU v5e's 128 MiB of VMEM
+# (2.8e6 entries at 2^18 buckets, 46 MiB, compile; 4e6 entries do not).
+TABLE_BYTES_MAX = 48 << 20
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("n_ev_max", "hits", "tw", "tau2", "eps", "peak_r",
                      "frac_bits", "seed_w", "seed_q", "minimizer_r",
-                     "levels", "clip_q", "step_q", "n_buckets", "n_entries",
+                     "levels", "clip_q", "step_q", "n_buckets",
                      "thresh_freq", "use_freq", "use_vote", "vlog2", "nbins",
-                     "thresh_vote", "tile", "interpret"))
-def cheap_fused_fixed(xq, bucket_bounds, entries_packed, *,
+                     "thresh_vote", "interpret"))
+def cheap_fused_fixed(xq, bucket_planes, entry_planes, *,
                       n_ev_max, hits, tw, tau2, eps, peak_r, frac_bits,
                       seed_w, seed_q, minimizer_r, levels, clip_q, step_q,
-                      n_buckets, n_entries, thresh_freq, use_freq, use_vote,
-                      vlog2, nbins, thresh_vote, tile, interpret=None):
+                      n_buckets, thresh_freq, use_freq, use_vote,
+                      vlog2, nbins, thresh_vote, interpret=None):
     """Launch the mega-kernel, one read per grid step.
 
-    xq             (R, S)      int32 Q-format signal
-    bucket_bounds  (2, NBpad)  int32 [start, end] of each bucket
-    entries_packed (2, Npad)   int32, NBpad and Npad % tile.bt == 0
+    xq             (R, S)  int32 Q-format signal
+    bucket_planes, entry_planes   bf16, the index as `index_planes` lays
+                   it out
     Returns t_pos (R, E*H) i32, hit (R, E*H) i32, counters (R, 9) i32.
     """
     if interpret is None:
         interpret = K.INTERPRET
+    assert hits <= _ROW, "a seed's window spans at most two table rows"
     r, s = xq.shape
-    bt = tile.bt
-    assert (bucket_bounds.shape[1] % bt == 0
-            and entries_packed.shape[1] % bt == 0)
     eh = n_ev_max * hits
     nc = len(COUNTER_COLS)
     kern = functools.partial(
         _kernel, n_ev_max=n_ev_max, hits=hits, tw=tw, tau2=tau2, eps=eps,
         peak_r=peak_r, frac_bits=frac_bits, seed_w=seed_w, seed_q=seed_q,
         minimizer_r=minimizer_r, levels=levels, clip_q=clip_q,
-        step_q=step_q, n_buckets=n_buckets, n_entries=n_entries,
+        step_q=step_q, n_buckets=n_buckets,
+        bound_bytes=bucket_planes.shape[0] // (2 * _ROW),
         thresh_freq=thresh_freq, use_freq=use_freq, use_vote=use_vote,
-        vlog2=vlog2, nbins=nbins, thresh_vote=thresh_vote, bt=bt,
-        nt_bs=bucket_bounds.shape[1] // bt,
-        nt_ent=entries_packed.shape[1] // bt)
+        vlog2=vlog2, nbins=nbins, thresh_vote=thresh_vote)
 
     # (R, 1, X) operands with the read axis squeezed out of each block: the
     # kernel sees one read's (1, X) row, a legal Mosaic block for any R
     def row(width):
         return pl.BlockSpec((None, 1, width), lambda i: (i, 0, 0))
 
+    # the same whole table at every step: fetched once, one buffer
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda i: (0, 0),
+                            pipeline_mode=pl.Buffered(1))
+
     t_pos, hit, cnt = pl.pallas_call(
         kern,
         grid=(r,),
-        in_specs=[row(s),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[row(s), whole(bucket_planes), whole(entry_planes)],
         out_specs=[row(eh), row(eh), row(nc)],
         out_shape=[
             jax.ShapeDtypeStruct((r, 1, eh), jnp.int32),
             jax.ShapeDtypeStruct((r, 1, eh), jnp.int32),
             jax.ShapeDtypeStruct((r, 1, nc), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, 2, bt), jnp.int32),
-            pltpu.VMEM((2, 2, bt), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_bytes(bucket_planes, entry_planes)),
         interpret=interpret,
-    )(xq.reshape(r, 1, s), bucket_bounds, entries_packed)
+    )(xq.reshape(r, 1, s), bucket_planes, entry_planes)
     return t_pos.reshape(r, eh), hit.reshape(r, eh), cnt.reshape(r, nc)

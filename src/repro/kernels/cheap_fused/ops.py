@@ -1,8 +1,8 @@
 """Public wrapper for the fused cheap-phase mega-kernel.
 
 Host graph: normalize + early-quantize the signals (same split as the
-event_detect wrapper), lay the bucket boundaries out as a (2, NB) table and
-pad both index tables to the DMA tile width, launch the mega-kernel once,
+event_detect wrapper), lay the bucket boundaries and the entry rows out as
+the kernel's byte planes (`index_planes`), launch the mega-kernel once,
 then rebuild the cheap-phase (q_pos, t_pos, hit_valid, counters)
 contract.
 """
@@ -16,38 +16,28 @@ from repro.core import events as ev
 from repro.core import stages
 from repro.core.config import MarsConfig
 from repro.kernels.cheap_fused.cheap_fused import (
-    COUNTER_COLS, DEFAULT_TILE, FusedTile, cheap_fused_fixed)
-
-
-def _pad_axis(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
-    n = x.shape[axis]
-    rem = -n % mult
-    if rem == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, rem)
-    return jnp.pad(x, widths)
+    COUNTER_COLS, TABLE_BYTES_MAX, cheap_fused_fixed, index_planes,
+    table_bytes)
 
 
 def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
-                cfg: MarsConfig, tile: FusedTile = DEFAULT_TILE):
+                cfg: MarsConfig):
     """signals: (R, S) f32 raw; index: the packed online index view.
 
     Returns (q_pos, t_pos, hit_valid, counters) — the exact
     ``pipeline.cheap_phase`` contract, bit-identical to the per-stage
-    pallas program for every config the `supports` gate admits.
+    pallas program for every config the `supports` gate admits — or None
+    when the index's tables exceed ``TABLE_BYTES_MAX``.
     """
     assert cfg.fixed_point and cfg.early_quantization, (
         "mega-kernel implements the MARS fixed-point path")
+    bounds, ent = index_planes(index["bucket_start"], index["entries_packed"])
+    if table_bytes(bounds, ent) > TABLE_BYTES_MAX:
+        return None
     x = ev.robust_normalize(signals)
     xq = ev.quantize_signal_fixed(x, cfg.frac_bits).astype(jnp.int32)
     r = xq.shape[0]
     e, h = cfg.max_events, cfg.max_hits_per_seed
-
-    n_entries = index["entries_packed"].shape[-1]
-    bstart = index["bucket_start"]
-    bounds = _pad_axis(jnp.stack([bstart[:-1], bstart[1:]]), 1, tile.bt)
-    ent = _pad_axis(index["entries_packed"], 1, tile.bt)
 
     clip_q = int(round(cfg.quant_clip_sigma * (1 << cfg.frac_bits)))
     t_pos, hit, cnt = cheap_fused_fixed(
@@ -59,10 +49,10 @@ def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
         seed_w=cfg.seed_width, seed_q=cfg.quant_bits,
         minimizer_r=cfg.minimizer_radius, levels=cfg.quant_levels,
         clip_q=clip_q, step_q=(2 * clip_q) // cfg.quant_levels,
-        n_buckets=cfg.n_buckets, n_entries=n_entries,
+        n_buckets=cfg.n_buckets,
         thresh_freq=cfg.thresh_freq, use_freq=cfg.use_freq_filter,
         use_vote=cfg.use_vote_filter, vlog2=cfg.voting_window_log2,
-        nbins=cfg.vote_bins, thresh_vote=cfg.thresh_voting, tile=tile)
+        nbins=cfg.vote_bins, thresh_vote=cfg.thresh_voting)
 
     t_pos = t_pos.reshape(r, e, h)
     hit_valid = hit.reshape(r, e, h).astype(bool)

@@ -2,19 +2,20 @@
 
 The contract under test: for every supported config the ONE-launch
 mega-kernel (detect -> quantize -> seed -> query -> vote, intermediates
-kernel-resident, index planes DMA-streamed tile by tile) is bit-identical
+kernel-resident, the probed index rows gathered from VMEM) is bit-identical
 to ``pipeline.cheap_phase(..., use_fused=False)`` (the per-stage batch
 program) and to ``pipeline.cheap_phase_vmap`` (the per-read reference
 ladder) — arrays AND every counter.  Unsupported configs must resolve to
 ``prims.fused is None`` and fall through the ladder unchanged.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import MarsConfig, build_index, pipeline, stages
 from repro.core.index import index_arrays
-from repro.kernels.cheap_fused import FusedTile, cheap_fused
+from repro.kernels.cheap_fused import cheap_fused
 from repro.kernels.cheap_fused import ref as fused_ref
 from repro.signal import simulate
 
@@ -73,36 +74,90 @@ def test_fused_matches_per_stage_and_vmap(setup):
                                       err_msg=f"counter {k!r}")
 
 
-@pytest.mark.parametrize("n_reads,tile", [
-    (1, FusedTile(bt=512)),
-    (3, FusedTile(bt=128)),
-    (5, FusedTile(bt=64)),
-])
-def test_fused_odd_shapes_and_tiles(setup, n_reads, tile):
-    """Odd read counts (one read per grid step) + small DMA tiles that
-    force many partial index sweeps must stay bit-exact."""
+def _assert_matches_both(signals, arrays, cfg):
+    """The fused kernel == the per-stage program == the vmap ladder, on
+    every array and every counter."""
+    plan = stages.resolve_plan(cfg, stages.PALLAS)
+    got = cheap_fused(signals, arrays, cfg)
+    _assert_cheap_equal(got, pipeline.cheap_phase(signals, arrays, cfg, plan,
+                                                  use_fused=False))
+    _assert_cheap_equal(got, pipeline.cheap_phase_vmap(signals, arrays, cfg,
+                                                       plan))
+
+
+def _probes(signals, arrays, cfg):
+    """(R, E) bucket id of every seed slot (the kernel gathers all E,
+    valid or not)."""
+    plan = stages.resolve_plan(cfg, stages.REFERENCE)
+
+    def keys(signal):
+        st = stages.execute_stages({"signal": signal, "counters": {}},
+                                   arrays, cfg, plan,
+                                   ("detect", "quantize", "seed"))
+        return st["keys"]
+    return np.asarray(jax.vmap(keys)(signals) & (cfg.n_buckets - 1))
+
+
+def _with_starts(arrays, bucket_start):
+    return dict(arrays, bucket_start=jnp.asarray(bucket_start, jnp.int32))
+
+
+@pytest.mark.parametrize("n_reads", [1, 3, 5])
+def test_fused_odd_shapes_and_tiles(setup, n_reads):
+    """Odd read counts (one read per grid step) stay bit-exact."""
     cfg, signals, arrays = setup
-    got = cheap_fused(signals[:n_reads], arrays, cfg, tile=tile)
-    want = fused_ref.cheap_fused_ref(signals[:n_reads], arrays, cfg)
-    gq, gt, gv, gc = got
-    wq, wt, wv, wc = want
-    np.testing.assert_array_equal(np.asarray(gv), np.asarray(wv))
-    np.testing.assert_array_equal(np.asarray(gq), np.asarray(wq))
-    np.testing.assert_array_equal(np.asarray(gt), np.asarray(wt))
-    for k in set(gc) & set(wc):
-        np.testing.assert_array_equal(np.asarray(gc[k]), np.asarray(wc[k]),
-                                      err_msg=f"counter {k!r}")
+    _assert_matches_both(signals[:n_reads], arrays, cfg)
 
 
-def test_fused_index_tile_boundary_probes(setup):
-    """A bucket whose entry range straddles a DMA tile edge must gather the
-    same entries as the untiled per-stage gather.  bt=32 on a 2^12-bucket /
-    multi-thousand-entry index guarantees straddling probes."""
+@pytest.mark.parametrize("case", ["straddle", "tail", "empty", "ends"])
+def test_fused_index_tile_boundary_probes(setup, case):
+    """The gather's edge cases, each shown to occur among the probes:
+    a seed's H-entry window straddling a 128-entry table row; buckets at
+    the table's last entries, whose slots past the end read entry N-1;
+    empty buckets; buckets 0 and n_buckets - 1 (a 64-bucket index, which
+    also leaves the bucket table shorter than one row)."""
+    cfg, signals, arrays = setup
+    h = cfg.max_hits_per_seed
+    if case == "ends":
+        cfg = cfg.replace(hash_bits=6)
+        ref = simulate.make_reference(6_000, seed=9)
+        arrays = index_arrays(build_index(ref.events_concat, ref.n_events,
+                                          cfg))
+        signals = signals[:3]
+    bs = np.asarray(arrays["bucket_start"]).astype(np.int64)
+    n = int(bs[-1])
+    probed = _probes(signals, arrays, cfg)
+    if case == "tail":
+        # the median probed bucket runs to the table's end (at least its
+        # last 3 entries), every later bucket is empty there
+        b = int(np.median(probed))
+        bs = np.minimum(bs, n - 3)
+        bs[b + 1:] = n
+        arrays = _with_starts(arrays, bs)
+        assert (bs[probed] + h > n).sum() > 1
+    elif case == "empty":
+        bs[1:-1:2] = bs[0:-2:2]               # every even bucket empty
+        arrays = _with_starts(arrays, bs)
+        assert (bs[probed + 1] == bs[probed]).sum() > 10
+    elif case == "straddle":
+        assert ((bs[probed] % 128) > 128 - h).sum() > 10
+    else:
+        assert {0, cfg.n_buckets - 1} <= set(probed.ravel().tolist())
+    _assert_matches_both(signals, arrays, cfg)
+
+
+def test_index_past_vmem_falls_back(setup, monkeypatch):
+    """An index whose tables would not fit the kernel's VMEM is declined,
+    and the ladder runs the per-stage program instead."""
+    from repro.kernels.cheap_fused import ops
     cfg, signals, arrays = setup
     plan = stages.resolve_plan(cfg, stages.PALLAS)
-    got = cheap_fused(signals, arrays, cfg, tile=FusedTile(bt=32))
-    want = pipeline.cheap_phase(signals, arrays, cfg, plan, use_fused=False)
-    _assert_cheap_equal(got, want)
+    monkeypatch.setattr(ops, "TABLE_BYTES_MAX", 0)
+    assert cheap_fused(signals[:2], arrays, cfg) is None
+    _assert_cheap_equal(
+        pipeline.cheap_phase(signals[:2], arrays, cfg, plan),
+        pipeline.cheap_phase(signals[:2], arrays, cfg, plan,
+                             use_fused=False))
 
 
 def test_supports_gate_rejects_tstat_overflow():

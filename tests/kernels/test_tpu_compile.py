@@ -122,12 +122,13 @@ def test_pluto_lookup_rows_compiles(one_chip, d1, mosaic):
 
 
 def test_cheap_fused_compiles(one_chip, d1, mosaic):
-    from repro.kernels.cheap_fused.cheap_fused import (DEFAULT_TILE,
-                                                       cheap_fused_fixed)
+    """The fused kernel at D1's index: 2^18 buckets and about 6e4 entries,
+    both tables resident in VMEM in the kernel's byte-plane layout."""
+    from repro.kernels.cheap_fused.cheap_fused import (cheap_fused_fixed,
+                                                       index_planes)
     cfg, arrays = d1
-    tile = DEFAULT_TILE
-    nb = cfg.n_buckets + (-cfg.n_buckets % tile.bt)
-    n = arrays["entries_packed"].shape[1]
+    bounds, ent = jax.eval_shape(index_planes, arrays["bucket_start"],
+                                 arrays["entries_packed"])
     clip_q = int(round(cfg.quant_clip_sigma * (1 << cfg.frac_bits)))
     _compile(lambda x, b, e: cheap_fused_fixed(
         x, b, e, n_ev_max=cfg.max_events, hits=cfg.max_hits_per_seed,
@@ -137,12 +138,12 @@ def test_cheap_fused_compiles(one_chip, d1, mosaic):
         seed_q=cfg.quant_bits, minimizer_r=cfg.minimizer_radius,
         levels=cfg.quant_levels, clip_q=clip_q,
         step_q=(2 * clip_q) // cfg.quant_levels, n_buckets=cfg.n_buckets,
-        n_entries=n, thresh_freq=cfg.thresh_freq,
+        thresh_freq=cfg.thresh_freq,
         use_freq=cfg.use_freq_filter, use_vote=cfg.use_vote_filter,
         vlog2=cfg.voting_window_log2, nbins=cfg.vote_bins,
-        thresh_vote=cfg.thresh_voting, tile=tile, interpret=False),
-        one_chip, ((R, cfg.signal_len), jnp.int32), ((2, nb), jnp.int32),
-        ((2, n + (-n % tile.bt)), jnp.int32))
+        thresh_vote=cfg.thresh_voting, interpret=False),
+        one_chip, ((R, cfg.signal_len), jnp.int32),
+        (bounds.shape, bounds.dtype), (ent.shape, ent.dtype))
 
 
 def test_pallas_chunk_program_compiles(one_chip, d1, mosaic):
