@@ -14,7 +14,8 @@ index from the genome's events and maps each read on its own:
     vote      anchors vote for two overlapping diagonal windows in a
               mod-hashed bin table; keep anchors of windows with enough
               votes
-    sort      anchors by (t, q), the first max_anchors kept
+    sort      anchors on the (t, q) pair, q clamped to 8 bits, the
+              first max_anchors kept; t is a whole int32 position
     chain     banded DP (look-back of chain_band anchors, oldest wins
               ties), best and second-best chain, the mapping decision
 
@@ -37,8 +38,10 @@ ISQRT_STEPS = 24           # fixed Newton steps of the integer sqrt
 DIAG_SHIFT = 1 << 20       # projected starts are shifted non-negative
 NEG = -1e9                 # score of an anchor that starts no chain
 SENTINEL = -(1 << 30)      # position of the band slots before anchor 0
-Q_BITS = 8                 # anchor sort key: [t : 23 bits | q : 8 bits]
-INVALID_KEY = 0x7FFFFFFF
+Q_BITS = 8                 # a read position q is clamped to 2^Q_BITS - 1
+INVALID_KEY = 0x7FFFFFFF   # t of an anchor not kept: it sorts last
+# positions are int32, and so is a diagonal t - q + DIAG_SHIFT
+MAX_CONCAT_EVENTS = (1 << 31) - DIAG_SHIFT
 
 COUNTERS = ("n_events", "n_seeds", "n_bucket_probes", "n_hits_raw",
             "n_hits_postfreq", "n_hits_exact", "n_votes_cast",
@@ -86,6 +89,10 @@ def build_index(events_concat: np.ndarray, n_events: int, p: dict) -> dict:
     """Every seed of the double genome, except those spanning the strand
     junction, as entries sorted by (bucket, key, position), each with the
     number of times its key occurs."""
+    if events_concat.shape[0] > MAX_CONCAT_EVENTS:
+        raise ValueError(
+            f"double genome of {events_concat.shape[0]} events: int32 "
+            f"positions and diagonals hold at most {MAX_CONCAT_EVENTS}")
     w, q = p["seed_width"], p["quant_bits"]
     sym = genome_symbols(events_concat, p)
     n = sym.shape[0] - w + 1
@@ -108,6 +115,24 @@ def build_index(events_concat: np.ndarray, n_events: int, p: dict) -> dict:
 # --------------------------------------------------------------------------- #
 # Per-read program (jax.numpy)
 # --------------------------------------------------------------------------- #
+def sort_anchors(t_pos, q_pos, keep, n_keep: int):
+    """The kept anchors sorted by the (t, q) pair, q clamped to Q_BITS,
+    and the first ``n_keep`` of them: (t, q, valid).  A slot past the
+    kept anchors reads t = ``INVALID_KEY >> Q_BITS``, q = ``2^Q_BITS - 1``
+    and valid False: the program's sentinels, from which a read with no
+    anchor takes its ``t_start``."""
+    import jax
+    import jax.numpy as jnp
+
+    q_max = (1 << Q_BITS) - 1
+    t = jnp.where(keep, t_pos, INVALID_KEY).reshape(-1)
+    q = jnp.where(keep, jnp.minimum(q_pos, q_max), q_max).reshape(-1)
+    st, sq = jax.lax.sort((t, q), num_keys=2)
+    st, sq = st[:n_keep], sq[:n_keep]
+    sv = st != INVALID_KEY
+    return jnp.where(sv, st, INVALID_KEY >> Q_BITS), sq, sv
+
+
 def _map_read(signal, index, p):
     import jax
     import jax.numpy as jnp
@@ -212,12 +237,7 @@ def _map_read(signal, index, p):
 
     # sort: anchors by (t, q), the first A kept
     A, B = p["max_anchors"], p["chain_band"]
-    akey = jnp.where(keep, (t_pos << Q_BITS)
-                     | jnp.minimum(q_pos, (1 << Q_BITS) - 1), INVALID_KEY)
-    skey = jnp.sort(akey.reshape(-1))[:A]
-    sv = skey != INVALID_KEY
-    st = skey >> Q_BITS
-    sq = skey & ((1 << Q_BITS) - 1)
+    st, sq, sv = sort_anchors(t_pos, q_pos, keep, A)
 
     # chain: banded DP over the sorted anchors
     ft = jnp.full(A + B, NEG, f32)
