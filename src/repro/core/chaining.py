@@ -2,7 +2,7 @@
 
 Anchors are sorted by (t_pos, q_pos) — MARS does this on the in-controller
 bitonic Sorter/Merger; the optimized pipeline path routes the sort through
-the `bitonic_sort` Pallas kernel, the reference path uses jnp.sort.  The DP
+the `bitonic_sort` Pallas kernel, the reference path uses lax.sort.  The DP
 is minimap2-style with a fixed look-back band B (MARS's Arithmetic Units are
 word-serial, so RawHash2's bounded-predecessor heuristic maps directly).
 
@@ -11,12 +11,22 @@ word-serial, so RawHash2's bounded-predecessor heuristic maps directly).
 
 The best chain's projected start (t_start - q_start) is the mapping position.
 
+Anchors are sorted on the (t, q) PAIR, lexicographically: t is a whole
+int32 double-genome position (below ``index.MAX_CONCAT_EVENTS`` = 2^31 -
+2^20) and q the read's event index, clamped to 8 bits as the plain
+reference clamps it; ``build_index`` refuses ``max_events`` past 256, so
+the clamp never changes a q.  The sorters carry q as a payload of t (``lax.sort``
+on two keys; the Pallas bitonic network compares pairs).  A slot holding no
+anchor sorts last (t = ``INVALID_T``) and decodes to the sentinel pair
+(``EMPTY_T``, ``EMPTY_Q``) from which a read with no anchor takes its
+``t_start``.
+
 Fast path (this module + core/pipeline.py): MARS's filters exist so that
 most reads reach chaining with few (often zero) anchors.  The chaining fast
 path exploits that:
 
   * ``select_smallest_count`` / ``select_smallest_topk`` pull the W smallest
-    packed keys out of the (E*H,) key array so the sorter runs on W keys
+    pairs out of the (E*H,) pair arrays so the sorter runs on W pairs
     instead of E*H ("select-then-sort" — the Pallas bitonic backend then
     sorts a W-slot block instead of the padded full block);
   * ``chain_dp`` carries only the B-slot band window as a ring buffer
@@ -41,14 +51,12 @@ from repro.core.config import MarsConfig
 
 NEG = -1e9
 _SENT = -(1 << 30)
-_INVALID_KEY = jnp.int32(0x7FFFFFFF)
-# packed sort key: [t_pos : 23 bits | q_pos : 8 bits] in a non-negative
-# int32 — requires the double genome to have < 2^(31-8) = 2^23 events and
-# max_events <= 2^8 = 256 (both checked at index build time; our scaled
-# datasets are far below).  int32 keys are what the bitonic Pallas kernel
-# sorts.
-_Q_BITS = 8
-T_BITS = 31 - _Q_BITS          # 23: t_pos field width (index.py guard)
+# t of a slot that holds no anchor: above every position, so it sorts last
+INVALID_T = 0x7FFFFFFF
+Q_MAX = (1 << 8) - 1           # largest q (index.check_index_limits)
+# the pair an empty slot decodes to (the chain phase's sentinels)
+EMPTY_T = INVALID_T >> 8
+EMPTY_Q = Q_MAX
 
 
 class ChainResult(NamedTuple):
@@ -60,52 +68,69 @@ class ChainResult(NamedTuple):
 
 
 # --------------------------------------------------------------------------- #
-# Key packing / selection
+# Sort pairs / selection
 # --------------------------------------------------------------------------- #
-def pack_anchor_keys(q_pos: jnp.ndarray, t_pos: jnp.ndarray,
-                     valid: jnp.ndarray) -> jnp.ndarray:
-    """Flatten (E,H) anchors into (E*H,) packed sort keys [t:23 | q:8];
-    invalid anchors become ``_INVALID_KEY`` (sorts last)."""
-    t = t_pos.reshape(-1).astype(jnp.int32)
-    q = jnp.minimum(q_pos.reshape(-1), (1 << _Q_BITS) - 1).astype(jnp.int32)
+def anchor_pairs(q_pos: jnp.ndarray, t_pos: jnp.ndarray, valid: jnp.ndarray):
+    """Flatten (E,H) anchors into (E*H,) sort pairs (t, q), q clamped to
+    ``Q_MAX``; invalid anchors become (``INVALID_T``, ``Q_MAX``) and sort
+    last.  The flattening is q-major (q_pos is the event index: slot
+    e*H + h holds event e), so slot order already breaks ties in t by q,
+    and a slot's q follows from its index (``slot_q``)."""
     v = valid.reshape(-1)
-    key = (t << _Q_BITS) | q
-    return jnp.where(v, key, _INVALID_KEY)
+    t = jnp.where(v, t_pos.reshape(-1).astype(jnp.int32), INVALID_T)
+    q = jnp.minimum(q_pos.reshape(-1), Q_MAX).astype(jnp.int32)
+    return t, jnp.where(v, q, Q_MAX)
 
 
-def decode_anchor_keys(skey: jnp.ndarray):
-    """Inverse of ``pack_anchor_keys`` on a sorted key array: (sq, st, sv)."""
-    sv = skey != _INVALID_KEY
-    st = (skey >> _Q_BITS).astype(jnp.int32)
-    sq = (skey & ((1 << _Q_BITS) - 1)).astype(jnp.int32)
-    return sq, st, sv
+def slot_q(idx: jnp.ndarray, hits: int) -> jnp.ndarray:
+    """The q ``anchor_pairs`` gives anchor slot ``idx`` of an (E, hits)
+    grid, worked out from the index instead of gathered."""
+    return jnp.minimum(idx // hits, Q_MAX).astype(jnp.int32)
 
 
-def select_smallest_count(key: jnp.ndarray, width: int) -> jnp.ndarray:
-    """The valid entries of ``key`` compacted to a (width,) array, padded
-    with ``_INVALID_KEY``.
+def sort_pairs(t: jnp.ndarray, q: jnp.ndarray):
+    """(t, q) sorted lexicographically: the reference sorter."""
+    return jax.lax.sort((t, q), num_keys=2)
+
+
+def decode_pairs(st: jnp.ndarray, sq: jnp.ndarray):
+    """Sorted pairs -> (sq, st, sv); empty slots read (EMPTY_T, EMPTY_Q)."""
+    sv = st != INVALID_T
+    return sq, jnp.where(sv, st, EMPTY_T), sv
+
+
+def select_smallest_count(t: jnp.ndarray, width: int, hits: int):
+    """The valid pairs of ``anchor_pairs``' (E*hits,) t compacted to
+    (width,) arrays (t, q), in slot order, padded with (``INVALID_T``,
+    ``Q_MAX``).
 
     Gather-based (cumsum + searchsorted): no scatter, so it vmaps into one
-    batched gather.  EXACT equivalent of ``sort(key)[:width]`` as a multiset
-    iff the number of valid keys is <= width — callers guarantee that with a
-    batch-level ``n_anchors_postvote`` bound (core/pipeline.py) before
-    taking this path.
+    batched gather of t; q comes from the slot index.  EXACT equivalent of
+    ``sort_pairs(t, q)[:width]`` as a multiset iff the number of valid
+    pairs is <= width — callers guarantee that with a batch-level
+    ``n_anchors_postvote`` bound (core/pipeline.py) before taking this
+    path.
     """
-    valid = key != _INVALID_KEY
+    valid = t != INVALID_T
     cum = jnp.cumsum(valid.astype(jnp.int32))
     idx = jnp.searchsorted(cum, jnp.arange(1, width + 1, dtype=jnp.int32),
                            side="left")
-    got = key[jnp.minimum(idx, key.shape[0] - 1)]
-    return jnp.where(jnp.arange(width) < cum[-1], got, _INVALID_KEY)
+    idx = jnp.minimum(idx, t.shape[0] - 1)
+    live = jnp.arange(width) < cum[-1]
+    return (jnp.where(live, t[idx], INVALID_T),
+            jnp.where(live, slot_q(idx, hits), Q_MAX))
 
 
-def select_smallest_topk(key: jnp.ndarray, width: int) -> jnp.ndarray:
-    """The ``width`` smallest keys, ascending, via ``lax.top_k`` on the
-    negated keys.  Exact for ANY valid count (true smallest-k selection);
-    on TPU top_k is a fast sampled-select, on CPU XLA lowers it to an
-    O(n*k) pass — cfg.anchor_select picks the strategy."""
-    neg = jax.lax.top_k(-key, width)[0]      # descending in -key
-    return -neg                               # ascending in key
+def select_smallest_topk(t: jnp.ndarray, width: int, hits: int):
+    """The ``width`` smallest pairs via ``lax.top_k`` on the negated t.
+    top_k puts the lower slot first among equal values, and slots are
+    q-major (``anchor_pairs``), so ties in t fall to the smaller q: exact
+    for ANY valid count.  On TPU top_k is a fast sampled-select, on CPU
+    XLA lowers it to an O(n*k) pass — cfg.anchor_select picks the
+    strategy."""
+    neg, idx = jax.lax.top_k(-t, width)
+    st = -neg
+    return st, jnp.where(st != INVALID_T, slot_q(idx, hits), Q_MAX)
 
 
 _SELECTORS = {
@@ -117,28 +142,26 @@ _SELECTORS = {
 def sort_anchors(q_pos: jnp.ndarray, t_pos: jnp.ndarray, valid: jnp.ndarray,
                  cfg: MarsConfig, sorter=None, width: int = None):
     """Sort (E,H) anchors by (t_pos, q_pos) with invalids last and keep the
-    first ``max_anchors``.  ``sorter(keys) -> sorted_keys`` is injectable
-    (Pallas bitonic kernel); default jnp.sort.
+    first ``max_anchors``.  ``sorter(t, q) -> (t, q)`` is injectable (Pallas
+    bitonic kernel); default ``sort_pairs``.
 
-    Packs (t_pos, q_pos) into an int32 key [t:23 | q:8] so the sort is a
-    single-key sort (what the in-controller bitonic Sorter consumes).
-
-    ``width=None`` sorts all E*H keys (the original full-sort behaviour).
-    ``width=W`` is the select-then-sort fast path: the W smallest keys are
+    ``width=None`` sorts all E*H pairs (the original full-sort behaviour).
+    ``width=W`` is the select-then-sort fast path: the W smallest pairs are
     selected first (strategy ``cfg.anchor_select``) and the sorter runs on
     the (W,) selection only — bit-identical to the full sort's first W slots
     provided the post-filter anchor count is <= W ("count" strategy) or
     unconditionally ("topk" strategy).
     """
     if sorter is None:
-        sorter = jnp.sort
-    key = pack_anchor_keys(q_pos, t_pos, valid)
+        sorter = sort_pairs
+    t, q = anchor_pairs(q_pos, t_pos, valid)
     if width is None:
-        skey = sorter(key)[: cfg.max_anchors]
+        st, sq = sorter(t, q)
+        st, sq = st[: cfg.max_anchors], sq[: cfg.max_anchors]
     else:
-        sel = _SELECTORS[cfg.anchor_select](key, width)
-        skey = sorter(sel)
-    return decode_anchor_keys(skey)
+        st, sq = sorter(*_SELECTORS[cfg.anchor_select](t, width,
+                                                       t_pos.shape[-1]))
+    return decode_pairs(st, sq)
 
 
 def sort_anchors_reference(q_pos, t_pos, valid, cfg: MarsConfig, sorter=None):
@@ -267,18 +290,16 @@ def empty_chain_result(cfg: MarsConfig) -> ChainResult:
     """The EXACT ChainResult the full sort+dp+finalize pipeline produces for
     a read with zero valid anchors — in closed form.
 
-    With no valid anchors every sorted slot holds ``_INVALID_KEY``; the DP
+    With no valid anchors every sorted slot holds ``INVALID_T``; the DP
     gives every slot f = NEG (invalid) and diag = t - q of the decoded
-    sentinel (its huge t fails the ``dt <= max_gap`` colinearity test against
+    sentinel pair (its huge t fails the ``dt <= max_gap`` colinearity test against
     every predecessor, so no extension can fire).  best_chain then sees an
     all-NEG score vector: argmax lands on slot 0, the second-best window is
     empty, and the result is a constant independent of A.  The read-
     compaction gate (core/pipeline.py) uses this to finalize filtered-out
     reads without running the chaining phase.
     """
-    st = int(_INVALID_KEY) >> _Q_BITS
-    sq = (1 << _Q_BITS) - 1
-    d = st - sq
+    d = EMPTY_T - EMPTY_Q
     return ChainResult(
         t_start=jnp.int32(max(d, 0)),
         score=jnp.float32(NEG),

@@ -22,9 +22,11 @@ is ONE two-word row:
         row 0   (key & ~bucket_mask) | cnt      key distinguisher + count
         row 1   t_pos                           seed position
 
-``seeding.query_index`` therefore serves a whole chunk with exactly TWO
-gathers (the fused bucket-boundary lookup and one entry-row lookup) instead
-of four table reads, and the pLUTo kernel answers each entry query with one
+On the device (``index_arrays``) the bucket table is held as its
+[start; end] pairs, ``bucket_bounds`` (2, B) int32, and both tables are
+padded to whole 128-entry tiles.  ``seeding.query_index`` therefore serves
+a whole chunk with exactly TWO gathers (one bucket-bounds lookup and one
+entry-row lookup) instead of four table reads, and the pLUTo kernel answers each entry query with one
 packed-row sweep (kernels/pluto_lookup reads both words per activation,
 like pLUTo's row-wide sense amplifiers).  ``build_index`` guards the
 packing statically: every count must fit the ``hash_bits`` spare bits.  The
@@ -40,7 +42,30 @@ from typing import Optional
 import numpy as np
 
 from repro.core.config import MarsConfig
-from repro.core import chaining, hashing
+from repro.core import hashing
+from repro.core.chaining import Q_MAX
+from repro.core.vote import DIAG_SHIFT
+
+
+# Largest double genome, in events: positions are int32, and so is every
+# vote diagonal t - q + DIAG_SHIFT (core/vote.py) the program forms from one
+# (the plain reference's ``MAX_CONCAT_EVENTS``).
+MAX_CONCAT_EVENTS = (1 << 31) - DIAG_SHIFT
+
+
+def check_index_limits(n_events: int, cfg: MarsConfig) -> None:
+    """Refuse a double genome past ``MAX_CONCAT_EVENTS`` events, and reads
+    of more events than the anchors' q_pos holds."""
+    if n_events > MAX_CONCAT_EVENTS:
+        raise ValueError(
+            f"double genome of {n_events} events: positions and vote "
+            f"diagonals are int32, so it may hold at most {MAX_CONCAT_EVENTS}"
+            " (2^31 - 2^20) events")
+    if cfg.max_events > Q_MAX + 1:
+        raise ValueError(
+            f"max_events {cfg.max_events}: the anchor sort clamps q_pos to "
+            f"{Q_MAX} (chaining.anchor_pairs, as the plain reference does), "
+            f"so a read may have at most {Q_MAX + 1} events")
 
 
 @dataclasses.dataclass
@@ -113,20 +138,7 @@ def quantize_reference_events(events: np.ndarray, cfg: MarsConfig,
 def build_index(ref_events_concat: np.ndarray, n_ref_events: int,
                 cfg: MarsConfig) -> Index:
     """ref_events_concat: (2*Le,) f32 — forward ++ reverse expected events."""
-    # overflow guard for the packed anchor sort key [t : T_BITS | q : Q_BITS]
-    # (chaining.pack_anchor_keys): every t_pos (double-genome coordinate,
-    # < 2*Le) must fit the t field of a NON-NEGATIVE int32, i.e.
-    # n_ref_events < 2^(31 - _Q_BITS) / 2 per strand.
-    if ref_events_concat.shape[0] >= (1 << chaining.T_BITS):
-        raise ValueError(
-            f"double genome must stay under 2^{chaining.T_BITS} events so "
-            "(t_pos, q_pos) packs into a non-negative int32 sort key "
-            "(chaining.pack_anchor_keys); shard larger references across "
-            "the model axis instead.")
-    if cfg.max_events > (1 << (31 - chaining.T_BITS)):
-        raise ValueError(
-            f"max_events must fit the {31 - chaining.T_BITS}-bit q_pos "
-            "field of the packed anchor sort key")
+    check_index_limits(ref_events_concat.shape[0], cfg)
     sym = quantize_reference_events(ref_events_concat.astype(np.float64), cfg)
     keys = hashing.pack_seeds_np(sym, cfg)                 # (2Le - w + 1,)
     pos = np.arange(keys.shape[0], dtype=np.int64)
@@ -169,12 +181,38 @@ def build_index(ref_events_concat: np.ndarray, n_ref_events: int,
     return idx
 
 
+# Entries (and buckets) in one tile of the device tables: one lane tile,
+# the unit the fused cheap kernel reads them in (kernels/cheap_fused).
+TILE = 128
+
+
+def bucket_bounds(bucket_start: np.ndarray) -> np.ndarray:
+    """(2, B') int32 [start; end] of each bucket, the B buckets padded to
+    whole tiles with empty buckets at the table's end."""
+    bs = np.asarray(bucket_start)
+    bounds = np.stack([bs[:-1], bs[1:]]).astype(np.int32)
+    return np.pad(bounds, ((0, 0), (0, -bounds.shape[1] % TILE)),
+                  constant_values=bs[-1])
+
+
+def pad_entries(packed: np.ndarray) -> np.ndarray:
+    """The (2, N) packed rows followed by copies of the last, to
+    (N // TILE + 2) * TILE columns: the two tiles from any bucket's start
+    exist, and every slot past the table reads entry N - 1, as a gather
+    clamped to the table does."""
+    n = packed.shape[1]
+    return np.pad(packed, ((0, 0), (0, (n // TILE + 2) * TILE - n)),
+                  mode="edge")
+
+
 def index_arrays(index: Index):
-    """The jit-friendly pytree of device arrays — packed two-plane layout
-    (``seeding.query_index``'s two-gather fast path)."""
+    """The jit-friendly pytree of device arrays, laid out once here for
+    every consumer of the resident table: ``bucket_bounds`` and the padded
+    ``entries_packed`` (``seeding.query_index``'s two gathers, and both
+    launches of the fused cheap kernel, which read them in tiles)."""
     return dict(
-        bucket_start=index.bucket_start,
-        entries_packed=index.entries_packed,
+        bucket_bounds=bucket_bounds(index.bucket_start),
+        entries_packed=pad_entries(index.entries_packed),
     )
 
 
@@ -434,16 +472,7 @@ def build_index_streaming(ref_events_concat: np.ndarray, n_ref_events: int,
     """
     from repro.core import driver
 
-    if ref_events_concat.shape[0] >= (1 << chaining.T_BITS):
-        raise ValueError(
-            f"double genome must stay under 2^{chaining.T_BITS} events so "
-            "(t_pos, q_pos) packs into a non-negative int32 sort key "
-            "(chaining.pack_anchor_keys); shard larger references across "
-            "the model axis instead.")
-    if cfg.max_events > (1 << (31 - chaining.T_BITS)):
-        raise ValueError(
-            f"max_events must fit the {31 - chaining.T_BITS}-bit q_pos "
-            "field of the packed anchor sort key")
+    check_index_limits(ref_events_concat.shape[0], cfg)
     if n_tiles < 1 or (n_tiles & (n_tiles - 1)):
         raise ValueError(f"n_tiles must be a power of two (tile owner is "
                          f"bucket >> log2(bucket_range)); got {n_tiles}")

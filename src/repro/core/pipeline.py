@@ -88,7 +88,8 @@ def cheap_phase(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
     Dispatch ladder, most-fused first: (1) the whole-phase mega-kernel
     (``stages.register_fused_cheap``) when the plan's cheap stages match one
     — detect..vote in ONE kernel launch, the probed index rows gathered
-    from VMEM (kernels/cheap_fused), for an index whose tables fit there;
+    from VMEM, or fetched from HBM by DMA for an index past the VMEM line
+    (kernels/cheap_fused);
     (2) the per-stage batch level below;
     (3) ``cheap_phase_vmap``.  ``use_fused=False`` pins level (2) — the
     fused-vs-per-stage microbenchmark pair and parity tests use it.
@@ -107,9 +108,7 @@ def cheap_phase(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
         return cheap_phase_vmap(signals, index, cfg, plan)
 
     if use_fused and prims.fused is not None and "t_pre_keys" not in index:
-        fused = prims.fused(signals, index)
-        if fused is not None:
-            return fused
+        return prims.fused(signals, index)
 
     if "t_pre_keys" in index:
         # the tiered traffic pre-pass already ran the plan's own
@@ -174,7 +173,7 @@ def chain_phase(q_pos: jnp.ndarray, t_pos: jnp.ndarray, hit_valid: jnp.ndarray,
     Runs at the smallest width W of ``cfg.chain_widths`` that bounds every
     active read's post-vote anchor count (``cnt``), falling back to the
     original full-sort path when none does: with cnt <= W the W smallest
-    packed keys are ALL surviving anchors, so select-then-sort at width W,
+    (t, q) pairs are ALL surviving anchors, so select-then-sort at width W,
     the banded DP over W slots and best_chain over W slots are bit-identical
     to the full-width pipeline (the truncated tail holds only invalid
     sentinel slots, which the DP maps to (NEG, const) and best_chain masks).
@@ -184,25 +183,28 @@ def chain_phase(q_pos: jnp.ndarray, t_pos: jnp.ndarray, hit_valid: jnp.ndarray,
     Returns (t_start (N,), score (N,), mapped (N,)) int32/f32/bool.
     """
     sorter, dp = prims
-    key = jax.vmap(chaining.pack_anchor_keys)(q_pos, t_pos, hit_valid)
+    t, q = jax.vmap(chaining.anchor_pairs)(q_pos, t_pos, hit_valid)
     select = chaining._SELECTORS[cfg.anchor_select]
     maxcnt = jnp.max(cnt)
 
-    def finalize(skey):
-        sq, st, sv = chaining.decode_anchor_keys(skey)
+    def finalize(st, sq):
+        sq, st, sv = chaining.decode_pairs(st, sq)
         f, d = jax.vmap(dp)(sq, st, sv)
         res = jax.vmap(lambda ff, dd, vv: chaining.best_chain(ff, dd, vv, cfg)
                        )(f, d, sv)
         return res.t_start, res.score, res.mapped
 
     def run_full():
-        return finalize(jax.vmap(lambda k: sorter(k)[: cfg.max_anchors])(key))
+        st, sq = jax.vmap(sorter)(t, q)
+        return finalize(st[:, : cfg.max_anchors], sq[:, : cfg.max_anchors])
 
     def run_at(width):
-        return finalize(jax.vmap(lambda k: sorter(select(k, width)))(key))
+        hits = t_pos.shape[-1]
+        return finalize(*jax.vmap(lambda a: sorter(*select(a, width, hits))
+                                  )(t))
 
     out = run_full
-    for w in reversed(_chain_widths(cfg, key.shape[1])):
+    for w in reversed(_chain_widths(cfg, t.shape[1])):
         def out(w_=w, fallback=out):
             return jax.lax.cond(maxcnt <= w_,
                                 functools.partial(run_at, w_), fallback)

@@ -11,7 +11,8 @@ Pallas kernel (kernels/pluto_lookup) instead of jnp.take.
 Packed-entry fast path: the online index stores the entries as (2, N) int32
 ROWS (``entries_packed``, core/index.py) — word 0 packs [key-distinguisher |
 count], word 1 holds t_pos — so ``query_index`` issues exactly TWO gathers
-per chunk: the fused bucket-boundary gather and ONE entry-row gather that
+per chunk: ONE row gather of the bucket bounds ``bucket_bounds`` (2, B)
+[start; end] and ONE entry-row gather that
 returns both words per probed entry (the pLUTo kernel reads the packed row
 in a single table sweep, like pLUTo's row-wide sense amplifiers; the
 unpacked layout needed three separate entry-table sweeps).  The unpacked
@@ -22,8 +23,8 @@ whole-chunk gathers (ONE pLUTo kernel sweep on the Pallas backend instead
 of per-read unit batches).
 
 Injectable ``gather(table, idx)`` contract: 1-D (N,) tables return
-``idx``-shaped values (as before); the 2-D (2, N) packed-row table returns
-(2, *idx.shape) — both words per index.
+``idx``-shaped values (as before); the 2-D (2, N) row tables (bucket
+bounds, packed entries) return (2, *idx.shape) — both words per index.
 """
 from __future__ import annotations
 
@@ -135,15 +136,15 @@ def query_index(keys: jnp.ndarray, valid: jnp.ndarray,
     mask = jnp.uint32(cfg.n_buckets - 1)
     bucket = (keys & mask).astype(jnp.int32)
 
-    # gather 1: both bucket boundaries (start of bucket b and of b+1) in one
-    # fused (2, ...) lookup
-    start_end = gather(index["bucket_start"],
-                       jnp.stack([bucket, bucket + 1]))      # (2, ..., E)
+    # gather 1: both bucket boundaries ([start; end] of bucket b) in one
+    # (2, ...) row lookup
+    start_end = gather(index["bucket_bounds"], bucket)       # (2, ..., E)
     start, end = start_end[0], start_end[1]
     cnt_bucket = end - start
 
     j = jnp.arange(H, dtype=jnp.int32)
     idx = start[..., None] + j                               # (..., E, H)
+    # the table's padding repeats its last entry (index.pad_entries)
     n_entries = index["entries_packed"].shape[-1]
     idx_c = jnp.minimum(idx, n_entries - 1)
 

@@ -288,8 +288,8 @@ def chain_primitives(plan: Plan, cfg: MarsConfig):
     ``primitive`` and a non-reference finalize must go through the per-read
     stage bodies instead).
 
-    Returns (sorter(keys)->keys, dp(q, t, valid)->(f, d)) — both per-read,
-    vmap-safe.
+    Returns (sorter(t, q)->(t, q) sorted by the pair, dp(q, t, valid)->(f, d))
+    — both per-read, vmap-safe.
     """
     p = dict(plan)
     if p["finalize"] != REFERENCE:
@@ -300,7 +300,7 @@ def chain_primitives(plan: Plan, cfg: MarsConfig):
         if b.name != REFERENCE and b.primitive is None:
             return None
         prims.append(b.primitive)
-    sorter = prims[0] if prims[0] is not None else jnp.sort
+    sorter = prims[0] if prims[0] is not None else chaining.sort_pairs
     if prims[1] is not None:
         dp_prim = prims[1]
         dp = lambda q, t, v: dp_prim(q, t, v, cfg)
@@ -315,10 +315,10 @@ class FusedCheapBackend:
 
     fn(signals (R,S), index, cfg) -> (q_pos, t_pos, hit_valid, counters) —
     the exact ``pipeline.cheap_phase`` contract, produced by ONE kernel
-    launch instead of per-stage programs — or None for an index the kernel
-    cannot hold.  ``supports`` gates configs the kernel cannot serve;
-    unsupported configs and declined indexes resolve to the per-stage plan
-    (pipeline.cheap_phase's existing dispatch ladder).
+    launch instead of per-stage programs, for an index of any size.
+    ``supports`` gates configs the kernel cannot serve; unsupported configs
+    resolve to the per-stage plan (pipeline.cheap_phase's existing
+    dispatch ladder).
     """
     name: str
     fn: Callable

@@ -12,9 +12,11 @@ min/max select runs on the VPU.  Stages with k <= 128 correspond to MARS's
 Sorter-128 blocks; the k > 128 stages are the Merger's merge passes — one
 kernel expresses both units.
 
-Block layout: ``lanes.row_block`` rows of anchor keys per program, (RB, L)
-int32 in VMEM, L a power of two (<= 8192 -> 32 KiB per row).  Ascending
-sort; pad with INT32_MAX.
+The network sorts (key, value) pairs lexicographically: an anchor's t
+with its q as the second key, so positions keep all 31 bits.  Block
+layout: ``lanes.row_block`` rows of keys and of values per program, two
+(RB, L) int32 blocks in VMEM, L a power of two (<= 8192 -> 32 KiB per
+row).  Ascending sort; pad both with INT32_MAX.
 """
 from __future__ import annotations
 
@@ -31,42 +33,53 @@ from repro.kernels import lanes
 MAX_BLOCK = 8192
 
 
-def _kernel(x_ref, out_ref):
-    x = x_ref[...]                                   # (RB, L) int32
-    L = x.shape[1]
-    lane = lanes.lane_iota(x)
-    k = 2
-    while k <= L:
-        j = k // 2
+def _kernel(k_ref, v_ref, ko_ref, vo_ref):
+    k, v = k_ref[...], v_ref[...]                    # (RB, L) int32 each
+    L = k.shape[1]
+    lane = lanes.lane_iota(k)
+
+    def partner(x, is_lo, j):
+        return jnp.where(is_lo, lanes.roll_left(x, j), lanes.roll_right(x, j))
+
+    s = 2
+    while s <= L:
+        j = s // 2
         while j >= 1:
             is_lo = (lane & j) == 0
-            p = jnp.where(is_lo, lanes.roll_left(x, j), lanes.roll_right(x, j))
-            # ascending run (bit k of i clear) or the final whole-block merge
-            take_min = is_lo if k == L else ((lane & k) == 0) == is_lo
-            x = jnp.where(take_min, jnp.minimum(x, p), jnp.maximum(x, p))
+            pk, pv = partner(k, is_lo, j), partner(v, is_lo, j)
+            # ascending run (bit s of i clear) or the final whole-block merge
+            take_min = is_lo if s == L else ((lane & s) == 0) == is_lo
+            below = (pk < k) | ((pk == k) & (pv < v))
+            above = (pk > k) | ((pk == k) & (pv > v))
+            take = (take_min & below) | (~take_min & above)
+            k, v = jnp.where(take, pk, k), jnp.where(take, pv, v)
             j //= 2
-        k *= 2
-    out_ref[...] = x
+        s *= 2
+    ko_ref[...] = k
+    vo_ref[...] = v
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitonic_sort(keys: jnp.ndarray, interpret: bool | None = None):
-    """keys: (B, L) int32, L power of two <= MAX_BLOCK.  Sorts each row
-    ascending (grid over row blocks; each row = one Sorter/Merger stream)."""
+def bitonic_sort(keys: jnp.ndarray, values: jnp.ndarray,
+                 interpret: bool | None = None):
+    """keys, values: (B, L) int32, L power of two <= MAX_BLOCK.  Sorts each
+    row's (key, value) pairs ascending (grid over row blocks; each row =
+    one Sorter/Merger stream)."""
     if interpret is None:
         interpret = K.INTERPRET
     B, L = keys.shape
     assert L & (L - 1) == 0 and L <= MAX_BLOCK, L
     rb = lanes.row_block(B)
-    xp = lanes.pad_rows(keys, rb)
-    out = pl.pallas_call(
+    kp, vp = lanes.pad_rows(keys, rb), lanes.pad_rows(values, rb)
+    block = pl.BlockSpec((rb, L), lambda b: (b, 0))
+    ko, vo = pl.pallas_call(
         _kernel,
-        grid=(xp.shape[0] // rb,),
-        in_specs=[pl.BlockSpec((rb, L), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((rb, L), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.int32),
+        grid=(kp.shape[0] // rb,),
+        in_specs=[block, block],
+        out_specs=[block, block],
+        out_shape=[jax.ShapeDtypeStruct(kp.shape, jnp.int32)] * 2,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-    )(xp)
-    return out[:B]
+    )(kp, vp)
+    return ko[:B], vo[:B]

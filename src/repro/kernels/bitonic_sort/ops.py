@@ -1,6 +1,7 @@
 """Public wrappers for the bitonic sort kernel + its stage-engine backend."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import stages
@@ -16,24 +17,28 @@ def _next_pow2(n: int) -> int:
     return p
 
 
-def sort_batch(keys: jnp.ndarray) -> jnp.ndarray:
-    """keys: (B, L) int32 -> each row sorted ascending."""
+def sort_batch(keys: jnp.ndarray, values: jnp.ndarray):
+    """keys, values: (B, L) int32 -> each row's (key, value) pairs sorted
+    ascending, as two (B, L) arrays."""
     B, L = keys.shape
     Lp = max(128, _next_pow2(L))
     if Lp > MAX_BLOCK:
         # beyond one VMEM block: fall back to XLA sort (documented limit;
         # the distributed pipeline shards anchors well below this).
-        return jnp.sort(keys, axis=-1)
+        return jax.lax.sort((keys, values), dimension=1, num_keys=2)
     if Lp != L:
         pad = jnp.full((B, Lp - L), _PAD, jnp.int32)
         keys = jnp.concatenate([keys, pad], axis=1)
-    out = bitonic_sort(keys.astype(jnp.int32))
-    return out[:, :L]
+        values = jnp.concatenate([values, pad], axis=1)
+    k, v = bitonic_sort(keys.astype(jnp.int32), values.astype(jnp.int32))
+    return k[:, :L], v[:, :L]
 
 
-def sort1d(keys: jnp.ndarray) -> jnp.ndarray:
-    """keys: (L,) int32 ascending.  vmap-safe via expand/squeeze."""
-    return sort_batch(keys.reshape(1, -1))[0]
+def sort1d(t: jnp.ndarray, q: jnp.ndarray):
+    """(L,) int32 pairs sorted ascending by (t, q).  vmap-safe via
+    expand/squeeze."""
+    k, v = sort_batch(t.reshape(1, -1), q.reshape(1, -1))
+    return k[0], v[0]
 
 
 def _sort_pallas(state, cfg, index):
@@ -43,7 +48,7 @@ def _sort_pallas(state, cfg, index):
 
 # ``sort1d`` doubles as the fast-path sorter primitive: under the
 # select-then-sort ladder (core/pipeline.chain_phase) it receives the (W,)
-# selected keys and sorts a 128/512-lane block instead of the padded full
+# selected pairs and sorts a 128/512-lane block instead of the padded full
 # E*H block.
 stages.register_backend("sort", stages.PALLAS, _sort_pallas,
                         primitive=sort1d)

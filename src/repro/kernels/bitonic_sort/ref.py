@@ -1,6 +1,7 @@
 """Pure-jnp oracle for bitonic_sort."""
+import jax
 import jax.numpy as jnp
 
 
-def sort_ref(keys: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sort(keys, axis=-1)
+def sort_ref(keys: jnp.ndarray, values: jnp.ndarray):
+    return jax.lax.sort((keys, values), dimension=-1, num_keys=2)

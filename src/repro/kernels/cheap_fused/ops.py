@@ -1,23 +1,26 @@
 """Public wrapper for the fused cheap-phase mega-kernel.
 
 Host graph: normalize + early-quantize the signals (same split as the
-event_detect wrapper), lay the bucket boundaries and the entry rows out as
-the kernel's byte planes (`index_planes`), launch the mega-kernel once,
-then rebuild the cheap-phase (q_pos, t_pos, hit_valid, counters)
-contract.
+event_detect wrapper), launch the mega-kernel once, then rebuild the
+cheap-phase (q_pos, t_pos, hit_valid, counters) contract.  The launch
+follows the index's size: up to ``TABLE_BYTES_MAX`` of byte planes
+(`index_planes`) the tables stay in VMEM (``cheap_fused_fixed``); past it
+they stay in HBM as the index dict holds them, and each read fetches what
+it probes by DMA (``cheap_fused_hbm``).
 """
 from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import events as ev
 from repro.core import stages
 from repro.core.config import MarsConfig
 from repro.kernels.cheap_fused.cheap_fused import (
-    COUNTER_COLS, TABLE_BYTES_MAX, cheap_fused_fixed, index_planes,
-    table_bytes)
+    COUNTER_COLS, TABLE_BYTES_MAX, cheap_fused_fixed, cheap_fused_hbm,
+    index_planes, table_bytes)
 
 
 def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
@@ -26,22 +29,24 @@ def cheap_fused(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
 
     Returns (q_pos, t_pos, hit_valid, counters) — the exact
     ``pipeline.cheap_phase`` contract, bit-identical to the per-stage
-    pallas program for every config the `supports` gate admits — or None
-    when the index's tables exceed ``TABLE_BYTES_MAX``.
+    pallas program for every config the `supports` gate admits, from
+    either launch.
     """
     assert cfg.fixed_point and cfg.early_quantization, (
         "mega-kernel implements the MARS fixed-point path")
-    bounds, ent = index_planes(index["bucket_start"], index["entries_packed"])
-    if table_bytes(bounds, ent) > TABLE_BYTES_MAX:
-        return None
+    tables = (index["bucket_bounds"], index["entries_packed"])
+    if table_bytes(*jax.eval_shape(index_planes, *tables)) <= TABLE_BYTES_MAX:
+        launch, tables = cheap_fused_fixed, index_planes(*tables)
+    else:
+        launch = cheap_fused_hbm
     x = ev.robust_normalize(signals)
     xq = ev.quantize_signal_fixed(x, cfg.frac_bits).astype(jnp.int32)
     r = xq.shape[0]
     e, h = cfg.max_events, cfg.max_hits_per_seed
 
     clip_q = int(round(cfg.quant_clip_sigma * (1 << cfg.frac_bits)))
-    t_pos, hit, cnt = cheap_fused_fixed(
-        xq, bounds, ent,
+    t_pos, hit, cnt = launch(
+        xq, *tables,
         n_ev_max=e, hits=h, tw=cfg.tstat_window,
         tau2=int(round(cfg.tstat_threshold ** 2)),
         eps=1 << (2 * cfg.frac_bits - 8),

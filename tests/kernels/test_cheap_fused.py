@@ -2,7 +2,8 @@
 
 The contract under test: for every supported config the ONE-launch
 mega-kernel (detect -> quantize -> seed -> query -> vote, intermediates
-kernel-resident, the probed index rows gathered from VMEM) is bit-identical
+kernel-resident, the probed index rows gathered from VMEM, or fetched
+from HBM by DMA past the VMEM line) is bit-identical
 to ``pipeline.cheap_phase(..., use_fused=False)`` (the per-stage batch
 program) and to ``pipeline.cheap_phase_vmap`` (the per-read reference
 ladder) — arrays AND every counter.  Unsupported configs must resolve to
@@ -14,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core import MarsConfig, build_index, pipeline, stages
-from repro.core.index import index_arrays
+from repro.core.index import bucket_bounds, index_arrays
 from repro.kernels.cheap_fused import cheap_fused
 from repro.kernels.cheap_fused import ref as fused_ref
 from repro.signal import simulate
@@ -98,8 +99,14 @@ def _probes(signals, arrays, cfg):
     return np.asarray(jax.vmap(keys)(signals) & (cfg.n_buckets - 1))
 
 
+def _starts(arrays, cfg):
+    """The (n_buckets + 1,) prefix offsets the device bounds hold."""
+    b = np.asarray(arrays["bucket_bounds"])[:, :cfg.n_buckets]
+    return np.append(b[0], b[1, -1]).astype(np.int64)
+
+
 def _with_starts(arrays, bucket_start):
-    return dict(arrays, bucket_start=jnp.asarray(bucket_start, jnp.int32))
+    return dict(arrays, bucket_bounds=jnp.asarray(bucket_bounds(bucket_start)))
 
 
 @pytest.mark.parametrize("n_reads", [1, 3, 5])
@@ -109,13 +116,9 @@ def test_fused_odd_shapes_and_tiles(setup, n_reads):
     _assert_matches_both(signals[:n_reads], arrays, cfg)
 
 
-@pytest.mark.parametrize("case", ["straddle", "tail", "empty", "ends"])
-def test_fused_index_tile_boundary_probes(setup, case):
-    """The gather's edge cases, each shown to occur among the probes:
-    a seed's H-entry window straddling a 128-entry table row; buckets at
-    the table's last entries, whose slots past the end read entry N-1;
-    empty buckets; buckets 0 and n_buckets - 1 (a 64-bucket index, which
-    also leaves the bucket table shorter than one row)."""
+def _boundary_case(setup, case):
+    """(cfg, signals, arrays) for one of the gather's edge cases, each
+    asserted to occur among the probes."""
     cfg, signals, arrays = setup
     h = cfg.max_hits_per_seed
     if case == "ends":
@@ -124,7 +127,7 @@ def test_fused_index_tile_boundary_probes(setup, case):
         arrays = index_arrays(build_index(ref.events_concat, ref.n_events,
                                           cfg))
         signals = signals[:3]
-    bs = np.asarray(arrays["bucket_start"]).astype(np.int64)
+    bs = _starts(arrays, cfg)
     n = int(bs[-1])
     probed = _probes(signals, arrays, cfg)
     if case == "tail":
@@ -143,21 +146,66 @@ def test_fused_index_tile_boundary_probes(setup, case):
         assert ((bs[probed] % 128) > 128 - h).sum() > 10
     else:
         assert {0, cfg.n_buckets - 1} <= set(probed.ravel().tolist())
+    return cfg, signals, arrays
+
+
+@pytest.mark.parametrize("case", ["straddle", "tail", "empty", "ends"])
+def test_fused_index_tile_boundary_probes(setup, case):
+    """The gather's edge cases, each shown to occur among the probes:
+    a seed's H-entry window straddling a 128-entry table row; buckets at
+    the table's last entries, whose slots past the end read entry N-1;
+    empty buckets; buckets 0 and n_buckets - 1 (a 64-bucket index, which
+    also leaves the bucket table shorter than one row)."""
+    cfg, signals, arrays = _boundary_case(setup, case)
+    _assert_matches_both(signals, arrays, cfg)
+
+
+def _hbm_launches(monkeypatch):
+    """Force the HBM launch (every index past the VMEM line) and count
+    its calls."""
+    from repro.kernels.cheap_fused import ops
+    calls = []
+    launch = ops.cheap_fused_hbm
+
+    def counted(*a, **kw):
+        calls.append(a[0].shape[0])
+        return launch(*a, **kw)
+    monkeypatch.setattr(ops, "TABLE_BYTES_MAX", 0)
+    monkeypatch.setattr(ops, "cheap_fused_hbm", counted)
+    return calls
+
+
+@pytest.mark.parametrize("case", ["straddle", "tail", "empty", "ends",
+                                  "one_read"])
+def test_hbm_launch_matches_vmem_launch(setup, case, monkeypatch):
+    """The HBM launch (bound and entry tiles by DMA, pipelined by one
+    read) == the VMEM launch == the per-stage program == the vmap ladder,
+    on every array and counter, on the gather's edge cases and on a
+    single read (a one-step pipeline)."""
+    if case == "one_read":
+        cfg, signals, arrays = setup
+        signals = signals[:1]
+    else:
+        cfg, signals, arrays = _boundary_case(setup, case)
+    vmem = cheap_fused(signals, arrays, cfg)
+    calls = _hbm_launches(monkeypatch)
+    hbm = cheap_fused(signals, arrays, cfg)
+    assert calls == [signals.shape[0]]
+    _assert_cheap_equal(hbm, vmem)
     _assert_matches_both(signals, arrays, cfg)
 
 
 def test_index_past_vmem_falls_back(setup, monkeypatch):
-    """An index whose tables would not fit the kernel's VMEM is declined,
-    and the ladder runs the per-stage program instead."""
-    from repro.kernels.cheap_fused import ops
+    """An index whose tables would not fit the kernel's VMEM takes the HBM
+    launch, inside the cheap phase's ladder, with the per-stage program's
+    answers."""
     cfg, signals, arrays = setup
     plan = stages.resolve_plan(cfg, stages.PALLAS)
-    monkeypatch.setattr(ops, "TABLE_BYTES_MAX", 0)
-    assert cheap_fused(signals[:2], arrays, cfg) is None
-    _assert_cheap_equal(
-        pipeline.cheap_phase(signals[:2], arrays, cfg, plan),
-        pipeline.cheap_phase(signals[:2], arrays, cfg, plan,
-                             use_fused=False))
+    calls = _hbm_launches(monkeypatch)
+    got = pipeline.cheap_phase(signals[:2], arrays, cfg, plan)
+    assert calls == [2]
+    _assert_cheap_equal(got, pipeline.cheap_phase(signals[:2], arrays, cfg,
+                                                  plan, use_fused=False))
 
 
 def test_supports_gate_rejects_tstat_overflow():

@@ -91,10 +91,10 @@ def test_event_detect_compiles(one_chip, d1, mosaic):
 @pytest.mark.parametrize("width", [128, 4096])
 def test_bitonic_sort_compiles(one_chip, mosaic, width):
     """The select-then-sort ladder's smallest block and the full E*H sort
-    (3072 keys padded to 4096)."""
+    (3072 (t, q) pairs padded to 4096)."""
     from repro.kernels.bitonic_sort.bitonic_sort import bitonic_sort
-    _compile(lambda k: bitonic_sort(k, interpret=False), one_chip,
-             ((R, width), jnp.int32))
+    _compile(lambda k, v: bitonic_sort(k, v, interpret=False), one_chip,
+             ((R, width), jnp.int32), ((R, width), jnp.int32))
 
 
 @pytest.mark.parametrize("anchors", [64, 512])
@@ -121,17 +121,10 @@ def test_pluto_lookup_rows_compiles(one_chip, d1, mosaic):
              ((R * cfg.max_events * cfg.max_hits_per_seed,), jnp.int32))
 
 
-def test_cheap_fused_compiles(one_chip, d1, mosaic):
-    """The fused kernel at D1's index: 2^18 buckets and about 6e4 entries,
-    both tables resident in VMEM in the kernel's byte-plane layout."""
-    from repro.kernels.cheap_fused.cheap_fused import (cheap_fused_fixed,
-                                                       index_planes)
-    cfg, arrays = d1
-    bounds, ent = jax.eval_shape(index_planes, arrays["bucket_start"],
-                                 arrays["entries_packed"])
+def _fused_params(cfg):
     clip_q = int(round(cfg.quant_clip_sigma * (1 << cfg.frac_bits)))
-    _compile(lambda x, b, e: cheap_fused_fixed(
-        x, b, e, n_ev_max=cfg.max_events, hits=cfg.max_hits_per_seed,
+    return dict(
+        n_ev_max=cfg.max_events, hits=cfg.max_hits_per_seed,
         tw=cfg.tstat_window, tau2=int(round(cfg.tstat_threshold ** 2)),
         eps=1 << (2 * cfg.frac_bits - 8), peak_r=cfg.peak_window,
         frac_bits=cfg.frac_bits, seed_w=cfg.seed_width,
@@ -141,7 +134,46 @@ def test_cheap_fused_compiles(one_chip, d1, mosaic):
         thresh_freq=cfg.thresh_freq,
         use_freq=cfg.use_freq_filter, use_vote=cfg.use_vote_filter,
         vlog2=cfg.voting_window_log2, nbins=cfg.vote_bins,
-        thresh_vote=cfg.thresh_voting, interpret=False),
+        thresh_vote=cfg.thresh_voting, interpret=False)
+
+
+# E. coli at MARS Table 2 row D2's 5 Mbp (bench/configs/ecoli_d2.json):
+# 2^22 buckets, 9,999,974 packed entries, far past the VMEM launch's line
+D2_CFG = dict(hash_bits=22, seed_width=9, thresh_freq=2000, thresh_voting=5)
+D2_ENTRIES = 9_999_974
+
+
+def _d2_index(one_chip):
+    cfg = datasets.config_for(datasets.DATASETS["D2"]).with_mode(
+        "ms_fixed").replace(**D2_CFG)
+    index = {"bucket_bounds": ((2, cfg.n_buckets), jnp.int32),
+             "entries_packed": ((2, (D2_ENTRIES // 128 + 2) * 128),
+                                jnp.int32)}
+    return cfg, {k: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for k, (s, d) in index.items()}
+
+
+def test_cheap_fused_hbm_compiles(one_chip, mosaic):
+    """The HBM launch at D2's index, its own trace name in the program."""
+    from repro.kernels.cheap_fused.cheap_fused import cheap_fused_hbm
+    cfg, index = _d2_index(one_chip)
+    bounds, ent = index["bucket_bounds"], index["entries_packed"]
+    compiled = _compile(
+        lambda x, b, e: cheap_fused_hbm(x, b, e, **_fused_params(cfg)),
+        one_chip, ((R, cfg.signal_len), jnp.int32),
+        (bounds.shape, bounds.dtype), (ent.shape, ent.dtype))
+    assert "cheap_fused_hbm" in compiled.as_text()
+
+
+def test_cheap_fused_compiles(one_chip, d1, mosaic):
+    """The fused kernel at D1's index: 2^18 buckets and about 6e4 entries,
+    both tables resident in VMEM in the kernel's byte-plane layout."""
+    from repro.kernels.cheap_fused.cheap_fused import (cheap_fused_fixed,
+                                                       index_planes)
+    cfg, arrays = d1
+    bounds, ent = jax.eval_shape(index_planes, arrays["bucket_bounds"],
+                                 arrays["entries_packed"])
+    _compile(lambda x, b, e: cheap_fused_fixed(x, b, e, **_fused_params(cfg)),
         one_chip, ((R, cfg.signal_len), jnp.int32),
         (bounds.shape, bounds.dtype), (ent.shape, ent.dtype))
 
@@ -161,3 +193,16 @@ def test_pallas_chunk_program_compiles(one_chip, d1, mosaic):
     compiled = map_chunk.lower(signals, index, cfg, n_valid=n_valid,
                                plan=plan).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_chunk_program_compiles_past_vmem(one_chip, mosaic):
+    """``map_chunk`` on the Pallas plan at D2's index: the fused cheap
+    phase takes its HBM launch, and no ``pluto_lookup`` sweep is left."""
+    cfg, index = _d2_index(one_chip)
+    plan = stages.resolve_plan(cfg, stages.PALLAS)
+    signals = jax.ShapeDtypeStruct((R, cfg.signal_len), jnp.float32,
+                                   sharding=one_chip)
+    n_valid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = map_chunk.lower(signals, index, cfg, n_valid=n_valid,
+                           plan=plan).compile().as_text()
+    assert "cheap_fused_hbm" in text and "pluto" not in text

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import MarsConfig, build_index, chaining, map_chunk, stages
+from repro.core import (MarsConfig, build_index, chaining, index,
+                        map_chunk, stages)
 from repro.core.index import index_arrays
 from repro.signal import simulate
 
@@ -46,15 +47,19 @@ def test_select_then_sort_matches_full_sort_prefix(n_valid, width):
     rng = np.random.default_rng(n_valid * 1000 + width)
     cfg = MarsConfig()
     q, t, v = _anchor_grid(rng, cfg.max_events, cfg.max_hits_per_seed,
-                           n_valid)
-    key = chaining.pack_anchor_keys(q, t, v)
-    full = jnp.sort(key)[:width]
-    count_sel = jnp.sort(chaining.select_smallest_count(key, width))
-    topk_sel = jnp.sort(chaining.select_smallest_topk(key, width))
-    if n_valid <= width:
-        np.testing.assert_array_equal(np.asarray(full), np.asarray(count_sel))
-    # topk selection is exact for ANY count
-    np.testing.assert_array_equal(np.asarray(full), np.asarray(topk_sel))
+                           n_valid, t_range=60)   # many equal t: q decides
+    h = cfg.max_hits_per_seed
+    pairs = chaining.anchor_pairs(q, t, v)
+    full = [x[:width] for x in chaining.sort_pairs(*pairs)]
+    count_sel = chaining.sort_pairs(
+        *chaining.select_smallest_count(pairs[0], width, h))
+    topk_sel = chaining.sort_pairs(
+        *chaining.select_smallest_topk(pairs[0], width, h))
+    for a, b, c in zip(full, count_sel, topk_sel):
+        if n_valid <= width:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # topk selection is exact for ANY count
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 def test_sort_anchors_width_matches_reference():
@@ -70,31 +75,41 @@ def test_sort_anchors_width_matches_reference():
 
 
 def test_packing_fields_round_trip():
-    """The packed key is [t : T_BITS | q : 8] in a non-negative int32.
-
-    The largest t_pos the index guard admits is 2^T_BITS - 2 (a double
-    genome of 2^T_BITS - 1 events): the (2^T_BITS - 1, 255) corner would
-    collide with the _INVALID_KEY sentinel."""
-    assert chaining.T_BITS == 31 - chaining._Q_BITS == 23
-    t = jnp.asarray([[0, (1 << chaining.T_BITS) - 2]], jnp.int32)
-    q = jnp.asarray([[5, (1 << chaining._Q_BITS) - 1]], jnp.int32)
-    v = jnp.ones((1, 2), bool)
-    key = chaining.pack_anchor_keys(q, t, v)
-    assert (np.asarray(key) >= 0).all()
-    sq, st, sv = chaining.decode_anchor_keys(key)
-    np.testing.assert_array_equal(np.asarray(st), t.reshape(-1))
-    np.testing.assert_array_equal(np.asarray(sq), q.reshape(-1))
-    assert np.asarray(sv).all()
+    """The sort pairs keep t whole: positions up to the index guard's
+    largest (``MAX_CONCAT_EVENTS`` - 1, far past 2^23) come back as they
+    went in, q clamped to 8 bits, and every one sorts before an empty slot."""
+    t_max = index.MAX_CONCAT_EVENTS - 1
+    t = jnp.asarray([[t_max, 0, (1 << 23) + 5, 1 << 30]], jnp.int32)
+    q = jnp.asarray([[5, 255, 300, 7]], jnp.int32)
+    v = jnp.asarray([[True, True, True, False]])
+    st, sq = chaining.sort_pairs(*chaining.anchor_pairs(q, t, v))
+    sq, st, sv = chaining.decode_pairs(st, sq)
+    np.testing.assert_array_equal(np.asarray(st),
+                                  [0, (1 << 23) + 5, t_max, chaining.EMPTY_T])
+    np.testing.assert_array_equal(np.asarray(sq), [255, 255, 5,
+                                                   chaining.EMPTY_Q])
+    np.testing.assert_array_equal(np.asarray(sv), [True, True, True, False])
 
 
 def test_index_build_rejects_key_overflow():
+    """``build_index`` refuses a double genome past 2^31 - 2^20 events
+    (int32 positions and vote diagonals) before touching its events, and
+    the guard admits every length up to that, 2^23 and 2^24 among them.
+    Reads of more than 256 events are refused: the sort pair clamps q to
+    8 bits."""
     cfg = MarsConfig()
-    too_big = np.zeros(1 << chaining.T_BITS, np.float32)
-    with pytest.raises(ValueError, match="sort key"):
+    too_big = np.broadcast_to(np.float32(0), (index.MAX_CONCAT_EVENTS + 1,))
+    with pytest.raises(ValueError, match="2\\^31 - 2\\^20"):
         build_index(too_big, too_big.shape[0] // 2, cfg)
-    with pytest.raises(ValueError, match="q_pos"):
-        build_index(np.zeros(64, np.float32), 32,
-                    cfg.replace(max_events=1 << (chaining._Q_BITS + 1)))
+    with pytest.raises(ValueError, match="2\\^31 - 2\\^20"):
+        index.build_index_streaming(too_big, too_big.shape[0] // 2, cfg, 4)
+    for n in (1 << 23, 1 << 24, index.MAX_CONCAT_EVENTS):
+        index.check_index_limits(n, cfg)
+    index.check_index_limits(64, cfg.replace(max_events=chaining.Q_MAX + 1))
+    for build in (build_index,
+                  lambda *a: index.build_index_streaming(*a, 4)):
+        with pytest.raises(ValueError, match="q_pos"):
+            build(np.zeros(64, np.float32), 32, cfg.replace(max_events=512))
 
 
 # --------------------------------------------------------------------------- #
@@ -128,8 +143,9 @@ def test_ring_dp_matches_reference(A, B):
 
 def test_ring_dp_all_invalid_is_empty_result():
     cfg = MarsConfig(max_anchors=64, chain_band=16)
-    key = jnp.full((64,), chaining._INVALID_KEY, jnp.int32)
-    sq, st, sv = chaining.decode_anchor_keys(key)
+    sq, st, sv = chaining.decode_pairs(
+        jnp.full((64,), chaining.INVALID_T, jnp.int32),
+        jnp.full((64,), chaining.Q_MAX, jnp.int32))
     f_r, d_r = chaining.chain_dp_reference(sq, st, sv, cfg)
     f_n, d_n = chaining.chain_dp(sq, st, sv, cfg)
     np.testing.assert_array_equal(np.asarray(f_r), np.asarray(f_n))
