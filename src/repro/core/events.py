@@ -12,14 +12,12 @@ Static shapes: each read yields exactly `max_events` event slots plus a
 validity count.  Segment means are computed as a one-hot segment-sum — the
 same formulation the `event_detect` Pallas kernel maps onto the MXU.
 
-Cheap-phase fast path (this PR's half of the PR-2 treatment): the float
-normalization sorts the signal ONCE and derives both the median and the MAD
-from the shared sorted array (``robust_normalize``); the fixed-point segment
-reduction replaces the two segment-sum scatters with cumsum-at-boundary
-gathers (``segment_means``).  Both are bit-identical to the previous
-implementations — kept here as ``robust_normalize_reference`` /
-``segment_means_reference`` parity oracles, exactly as PR 2 kept
-``chain_dp_reference``.
+Cheap-phase fast path: the float normalization sorts the signal once and
+takes both the median and the MAD from the shared sorted array
+(``robust_normalize``); the fixed-point segment reduction replaces the two
+segment-sum scatters with cumsum-at-boundary gathers (``segment_means``).
+Both are bit-identical to the plain implementations, kept as the parity
+oracles ``robust_normalize_reference`` / ``segment_means_reference``.
 """
 from __future__ import annotations
 
@@ -50,22 +48,18 @@ def robust_normalize_reference(signal: jnp.ndarray) -> jnp.ndarray:
     return (signal - med) / scale
 
 
-def _median_two_sorted(a: jnp.ndarray, b: jnp.ndarray, m1: int, m2: int):
-    """Values at ranks ``m1 <= m2`` of the merged multiset of two sorted 1-D
-    arrays, via stable-merge rank arithmetic (no sort of the union).
+def _kth_of_two_sorted(a: jnp.ndarray, b: jnp.ndarray, k: int):
+    """The k-th smallest (0-based) of the union of two ascending 1-D runs.
 
-    rank(a[i]) counts b-elements strictly smaller; rank(b[j]) counts
-    a-elements smaller-or-equal — together a permutation of 0..len(a+b)-1
-    (the stable merge), so each rank selects exactly one element.
+    Taking i elements from ``a`` and k+1-i from ``b``, the largest taken is
+    max(a[i-1], b[k-i]) (-inf for an empty side); the k-th smallest is the
+    least of these over every feasible i.  k and both lengths are static, so
+    the candidates are static slices: one maximum and one min reduction,
+    with no gather, loop or sort.
     """
-    ra = jnp.arange(a.shape[0]) + jnp.searchsorted(b, a, side="left")
-    rb = jnp.arange(b.shape[0]) + jnp.searchsorted(a, b, side="right")
-
-    def at(k):
-        return (jnp.sum(jnp.where(ra == k, a, 0.0)) +
-                jnp.sum(jnp.where(rb == k, b, 0.0)))
-
-    return at(m1), at(m2)
+    lo, hi = max(0, k + 1 - b.shape[0]), min(k + 1, a.shape[0])
+    ae, be = (jnp.pad(v, (1, 0), constant_values=-jnp.inf) for v in (a, b))
+    return jnp.min(jnp.maximum(ae[lo:hi + 1], be[k + 1 - hi:k + 2 - lo][::-1]))
 
 
 def _robust_normalize_row(signal: jnp.ndarray) -> jnp.ndarray:
@@ -74,8 +68,8 @@ def _robust_normalize_row(signal: jnp.ndarray) -> jnp.ndarray:
     The median interpolation mirrors jnp.quantile's "linear" method at
     q=0.5 exactly (lo*0.5 + hi*0.5 — for odd S, lo == hi).  |x - med| over
     the sorted signal is two sorted runs (descending-left, ascending-right
-    of the median), so the MAD is the median of a 2-way merge — rank
-    selection instead of a second full sort.
+    of the median), so the MAD's two ranks are selected from the pair of
+    runs (``_kth_of_two_sorted``) instead of a second full sort.
     """
     S = signal.shape[0]
     m1, m2 = (S - 1) // 2, S // 2
@@ -85,7 +79,8 @@ def _robust_normalize_row(signal: jnp.ndarray) -> jnp.ndarray:
     h = S // 2
     dev_lo = (med - xs[:h])[::-1]        # ascending: xs[:h] <= med
     dev_hi = xs[h:] - med                # ascending: xs[h:] >= med
-    lo, hi = _median_two_sorted(dev_lo, dev_hi, m1, m2)
+    lo = _kth_of_two_sorted(dev_lo, dev_hi, m1)
+    hi = _kth_of_two_sorted(dev_lo, dev_hi, m2)
     mad = lo * half + hi * half
     scale = 1.4826 * mad + _EPS
     return (signal - med) / scale
@@ -94,7 +89,7 @@ def _robust_normalize_row(signal: jnp.ndarray) -> jnp.ndarray:
 def robust_normalize(signal: jnp.ndarray) -> jnp.ndarray:
     """Per-read median/MAD normalization (f32).  signal: (..., S).
 
-    One shared sort per read: the MAD median is rank-selected from the
+    One shared sort per read: the MAD's two ranks are selected from the
     sorted signal instead of sorting |x - med| again.  Bit-identical to
     ``robust_normalize_reference`` (asserted by tests/test_cheap_fastpath).
     """

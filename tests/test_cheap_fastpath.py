@@ -5,7 +5,8 @@ seed implementations.
 Mirrors the fast path's structure (and tests/test_chain_fastpath.py):
 
   (a) event reduction: one-sort ``robust_normalize`` vs the two-median
-      reference; cumsum-at-boundary ``segment_means`` vs the segment-sum
+      reference, its rank selection vs a sorted union, its lowered
+      program (no loop, no gather); cumsum-at-boundary ``segment_means`` vs the segment-sum
       reference; full ``detect_events`` vs ``detect_events_reference`` —
       swept over the fixed-point x early-quant x float mode grid;
   (b) the int32 overflow guard of the integer boundary test (satellite:
@@ -46,18 +47,79 @@ def mode_setup(request):
 # --------------------------------------------------------------------------- #
 # (a) prefix-sum event reduction vs reference oracles
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("S", [7, 8, 255, 256, 1024])
-def test_robust_normalize_matches_reference(S):
-    """One shared sort + rank-merged MAD == two jnp.median sorts, bitwise
-    (odd/even lengths, heavy ties)."""
+def _kth_cases():
+    """(run lengths, fill, ranks): every rank at the small lengths, the
+    ends and the middle at the long ones."""
+    for na, nb in [(0, 1), (1, 1), (1, 2), (511, 513), (512, 512)]:
+        n = na + nb
+        ks = (range(n) if n <= 3 else
+              sorted({0, 1, n // 2 - 1, n // 2, n - 2, n - 1}))
+        for fill in ("normal", "rounded", "equal"):
+            yield pytest.param(na, nb, fill, tuple(ks),
+                               id=f"{na}-{nb}-{fill}")
+
+
+@pytest.mark.parametrize("na,nb,fill,ks", list(_kth_cases()))
+def test_kth_of_two_sorted_matches_sorted_union(na, nb, fill, ks):
+    """The MAD's rank selection == the k-th entry of the sorted union,
+    with ties inside and across the two runs."""
+    rng = np.random.default_rng(1000 * na + nb)
+    a, b = (rng.normal(0, 3, n).astype(np.float32) for n in (na, nb))
+    if fill == "rounded":
+        a, b = np.round(a), np.round(b)
+    elif fill == "equal":
+        a, b = np.full(na, 2.0, np.float32), np.full(nb, 2.0, np.float32)
+    a, b = np.sort(a), np.sort(b)
+    union = np.sort(np.concatenate([a, b]))
+    for k in ks:
+        got = events._kth_of_two_sorted(jnp.asarray(a), jnp.asarray(b), k)
+        assert np.asarray(got) == union[k], (k, got, union[k])
+
+
+def _normalize_case(S, kind, rng):
+    """Rows for ``test_robust_normalize_matches_reference``: normal
+    samples; a constant signal (MAD 0, scale _EPS); or one where over half
+    the samples equal the median (MAD 0 with distinct values around)."""
+    x = np.round(rng.normal(100, 25, (3, S))).astype(np.float32)
+    if kind == "constant":
+        x[:] = 97.0
+    elif kind == "median_ties":
+        x[:, rng.permutation(S)[:S // 2 + 1]] = 101.0
+    return x
+
+
+@pytest.mark.parametrize("S,kind", [
+    *(pytest.param(S, "random", id=str(S))
+      for S in (7, 8, 255, 256, 1024, 1, 2, 3)),
+    *(pytest.param(S, kind, id=f"{S}-{kind}")
+      for S in (1, 2, 3, 8, 1024) for kind in ("constant", "median_ties"))])
+def test_robust_normalize_matches_reference(S, kind):
+    """One shared sort + rank-selected MAD == two jnp.median sorts, bitwise
+    (odd/even lengths, heavy ties, MAD 0), eager against eager and jit
+    against jit (the two differ in the division's last ulp)."""
     rng = np.random.default_rng(S)
     for trial in range(4):
-        x = rng.normal(100, 25, (3, S)).astype(np.float32)
-        if trial % 2:
-            x = np.round(x)                    # ties exercise rank merging
-        got = events.robust_normalize(jnp.asarray(x))
-        want = events.robust_normalize_reference(jnp.asarray(x))
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        if kind == "random":
+            x = rng.normal(100, 25, (3, S)).astype(np.float32)
+            if trial % 2:
+                x = np.round(x)                # ties across the two runs
+        else:
+            x = _normalize_case(S, kind, rng)
+        x = jnp.asarray(x)
+        for run in (lambda f: f, jax.jit):
+            got = run(events.robust_normalize)(x)
+            want = run(events.robust_normalize_reference)(x)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_robust_normalize_lowers_without_loops_or_gathers():
+    """The MAD's selection is static slices, not a batched binary search:
+    the chunk-sized program holds one sort and no while loop or gather."""
+    x = jax.ShapeDtypeStruct((256, 1024), jnp.float32)
+    text = jax.jit(events.robust_normalize).lower(x).as_text()
+    assert "stablehlo.sort" in text
+    assert "while" not in text
+    assert "gather" not in text
 
 
 def test_segment_means_matches_reference_fixed():
