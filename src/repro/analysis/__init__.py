@@ -1,1 +1,2 @@
-"""Compiled-artifact analysis: roofline terms, HLO collective accounting."""
+"""Compiled-artifact analysis: loop-aware flops, bytes and collective wire
+bytes of an HLO module (``hlo.analyze``)."""

@@ -2,10 +2,11 @@
 
 Why not `compiled.cost_analysis()` alone: XLA's cost analysis counts each
 `while` body ONCE, not x trip-count (verified experimentally — a 10-step
-scan of a matmul reports the flops of one matmul).  Our stacks scan over
-layer groups, so everything interesting lives inside whiles.  This module
-walks the computation call graph from ENTRY, multiplying by loop trip
-counts (parsed from each while's condition), and accumulates:
+scan of a matmul reports the flops of one matmul).  The partitioned
+query schedules scan over the index partitions, so their collectives live
+inside whiles.  This module walks the computation call graph from ENTRY,
+multiplying by loop trip counts (parsed from each while's condition), and
+accumulates:
 
   * flops      — 2 * prod(result dims) * prod(contracting dims) per dot
                  (operand shapes resolved through the computation's SSA
@@ -17,8 +18,8 @@ counts (parsed from each while's condition), and accumulates:
                  collective-permute 1x.  Shapes in the partitioned module
                  are PER-DEVICE, so totals are per-device.
 
-Structural estimates (no fabric model), but consistent across cells and
-optimizations — which is what the roofline iteration needs.
+Structural estimates (no fabric model), but consistent across programs —
+which is what comparing two collective schedules needs.
 """
 from __future__ import annotations
 
@@ -254,19 +255,7 @@ def analyze(text: str) -> Dict[str, float]:
                 continue
             base = op.kind.replace("-start", "")
             if base in _COLLECTIVES and not op.kind.endswith("-done"):
-                nbytes = op.result_bytes
-                # bf16-legalization correction: the XLA CPU backend upcasts
-                # every bf16 dot to f32, so weight/activation gathers feeding
-                # dots appear as f32 collectives (verified: 0 bf16 dots in
-                # the llama3-405b module).  A collective whose operand is a
-                # convert-from-bf16 fusion is bf16 on the TPU target —
-                # count it at half width.
-                if "f32[" in op.line:
-                    src = comp.table.get(op.operands[0]) if op.operands else None
-                    if src is not None and ("convert" in src.name
-                                            or "convert" in src.kind):
-                        nbytes //= 2
-                coll[base] += mult * nbytes * _WIRE_FACTOR[base]
+                coll[base] += mult * op.result_bytes * _WIRE_FACTOR[base]
                 counts[base] += mult
                 for c in op.called:          # all-reduce reducer (tiny)
                     walk(c, mult, True, stack)
@@ -295,19 +284,3 @@ def analyze(text: str) -> Dict[str, float]:
     out.update({f"count_{k}": v for k, v in counts.items()})
     out["total"] = sum(coll.values())
     return out
-
-
-def collective_bytes(text: str) -> Dict[str, float]:
-    """Back-compat wrapper: collective wire bytes (loop-aware)."""
-    a = analyze(text)
-    return {k: v for k, v in a.items()
-            if k.startswith(("bytes_", "count_", "total"))}
-
-
-def op_histogram(hlo_text: str, top: int = 15) -> Dict[str, int]:
-    ops = re.findall(r"=\s*[a-z0-9]+\[[^\]]*\][^ ]*\s+([a-z\-]+)\(",
-                     hlo_text)
-    hist: Dict[str, int] = {}
-    for o in ops:
-        hist[o] = hist.get(o, 0) + 1
-    return dict(sorted(hist.items(), key=lambda kv: -kv[1])[:top])

@@ -3,7 +3,7 @@
 
 MARS distributes raw reads across flash channels and queries index
 partitions sequentially, overlapping partition loads with compute
-(paper Section 6.3).  The TPU mapping (DESIGN.md Section 3):
+(paper Section 6.3).  The TPU mapping:
 
   * reads are sharded over ALL mesh axes (every chip maps its own reads —
     the "channel stripe");
@@ -28,9 +28,11 @@ width ladder, and the exact ``stages.CHUNK_COUNTER_SCHEMA`` (per-read
 counter partials ride home with the hits, so pad-row masking via
 ``n_valid`` works in the distributed path too).
 
-``make_distributed_mapper`` survives as a thin compatibility wrapper over
-the shared sharded chunk program; ``partition_index`` lives next to Index
-construction in ``core/index.py`` and is re-exported here.
+The two layouts the sharded chunk program places its arguments with live
+here too: ``mapping_chunk_shardings`` (reads over every mesh axis, index
+replicated) and ``partitioned_index_shardings``.
+``partition_index`` lives next to Index construction in ``core/index.py``
+and is re-exported here.
 """
 from __future__ import annotations
 
@@ -39,6 +41,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import seeding, stages
 from repro.core.config import MarsConfig
@@ -149,10 +152,10 @@ def _query_ring(state: stages.State, cfg: MarsConfig, index) -> stages.State:
 
 
 def _query_a2a(state: stages.State, cfg: MarsConfig, index) -> stages.State:
-    """All-to-all schedule (§Perf iteration, default): only (keys, valid)
-    rotate; hits and counter partials accumulate locally per source rank and
-    ONE all_to_all returns them home — the (E,H) hit payload crosses the
-    wire once instead of n_parts times."""
+    """All-to-all schedule: only (keys, valid) rotate; hits and counter
+    partials accumulate locally per source rank and ONE all_to_all returns
+    them home — the (E,H) hit payload crosses the wire once instead of
+    n_parts times."""
     part, n_parts = _partition_view(index, cfg)
     keys, valid = state["keys"], state["seed_valid"]
     E, H = keys.shape[0], cfg.max_hits_per_seed
@@ -207,31 +210,23 @@ stages.register_backend("query", "a2a", _query_a2a,
 
 
 # --------------------------------------------------------------------------- #
-# Compatibility wrappers (legacy distributed-mapper API)
+# Argument layouts of the sharded chunk program
 # --------------------------------------------------------------------------- #
-def make_distributed_mapper(cfg: MarsConfig, mesh, schedule: str = "a2a"):
-    """Thin compatibility wrapper: the old (signals, parts) ->
-    (t_start, score, mapped, counters) jit signature over the SHARED sharded
-    chunk program (``pipeline.map_chunk_sharded``'s body) with the
-    ``query:ring`` / ``query:a2a`` backend.
+def mapping_chunk_shardings(mesh: Mesh):
+    """Layouts for the sharded map_chunk path (core/pipeline.py): raw reads
+    sharded over EVERY mesh axis (the MARS "channel stripe" — each chip
+    maps its own reads), and a replicated index on all chips (a
+    partitioned one takes ``partitioned_index_shardings``).
 
-    New code should call ``stages.resolve_plan(cfg, schedule)`` +
-    ``pipeline.map_chunk_sharded`` (or drive chunks through ``Mapper`` /
-    ``core/driver.py``) directly; counters now carry the full
-    ``stages.CHUNK_COUNTER_SCHEMA``.
-    """
-    from repro.core.pipeline import sharded_chunk_fn
-    plan = stages.resolve_plan(cfg, schedule)
-    inner = sharded_chunk_fn(cfg, mesh, plan)
-
-    def fn(signals, parts):
-        t, s, m, _, counters = inner(signals, parts,
-                                     jnp.int32(signals.shape[0]))
-        return t, s, m, counters
-    return jax.jit(fn)
+    Returns (signals sharding for (R, S), replicated index sharding)."""
+    return (NamedSharding(mesh, P(tuple(mesh.axis_names), None)),
+            NamedSharding(mesh, P()))
 
 
-def input_shardings(mesh):
-    """(signals sharding, partitioned-index shardings) for the wrapper."""
-    from repro.distributed.sharding import mapping_chunk_shardings
-    return mapping_chunk_shardings(mesh, partitioned_index=True)
+def partitioned_index_shardings(mesh: Mesh):
+    """Shardings for the ``core/index.partition_index`` pytree: the leading
+    partition axis of every leaf over ``index.INDEX_AXIS``, so each chip
+    holds exactly its resident bucket-range partition (the flash-partition
+    layout of paper Section 6.3)."""
+    return {k: NamedSharding(mesh, P(INDEX_AXIS))
+            for k in PARTITIONED_INDEX_KEYS}

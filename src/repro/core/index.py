@@ -230,14 +230,14 @@ def index_arrays_unpacked(index: Index):
 # --------------------------------------------------------------------------- #
 # Range partitioning (distributed query backends)
 # --------------------------------------------------------------------------- #
-# The mesh axis holding index partitions (the TP axis of the production
-# mesh, launch/mesh.py) — the ONE name the query backends' collectives,
-# the shard_map in_specs and the partition shardings all key on.
+# The mesh axis holding index partitions — the ONE name the query
+# backends' collectives, the shard_map in_specs and the partition
+# shardings all key on.
 INDEX_AXIS = "model"
 
 # The pytree keys of a partitioned index (every leaf has a leading
 # (n_parts,) partition axis, sharded over INDEX_AXIS by
-# distributed/sharding.partitioned_index_shardings).  The entry plane is
+# core/distributed.partitioned_index_shardings).  The entry plane is
 # the SAME packed [keycnt | t_pos] layout as the replicated table
 # (entries_packed above), per partition.
 PARTITIONED_INDEX_KEYS = ("p_bucket_start", "p_entries_packed")

@@ -389,9 +389,9 @@ def sharded_chunk_fn(cfg: MarsConfig, mesh, plan: stages.Plan):
     ``fn(signals (R,S), index pytree, n_valid) -> (t_start, score, mapped,
     n_events, counters)``.  Public accessor for callers that need the raw
     program rather than ``map_chunk_sharded``'s host conveniences — e.g.
-    the legacy distributed-mapper wrapper and abstract ``.lower`` dry-runs
-    (launch/dryrun.py), where device_put on ShapeDtypeStructs is
-    impossible.  Cached per (cfg, mesh, plan)."""
+    an abstract ``.lower`` for a described topology
+    (tests/kernels/test_tpu_compile_mesh.py), where device_put on
+    ShapeDtypeStructs is impossible.  Cached per (cfg, mesh, plan)."""
     return _sharded_chunk_fn(cfg, mesh, plan)
 
 
@@ -436,7 +436,7 @@ def map_chunk_sharded(signals: jnp.ndarray, index: Dict[str, jnp.ndarray],
         raise ValueError(f"plan {plan} partitions the index over the "
                          f"'{INDEX_AXIS}' axis, absent from mesh "
                          f"{mesh.axis_names}")
-    from repro.distributed.sharding import mapping_chunk_shardings
+    from repro.core.distributed import mapping_chunk_shardings
     sig_sh, _ = mapping_chunk_shardings(mesh)
     signals = jax.device_put(signals, sig_sh)
     nv = jnp.int32(R if n_valid is None else n_valid)
@@ -521,7 +521,7 @@ class Mapper:
             self.arrays = None
         elif stages.plan_index_kind(self.plan) == "partitioned":
             from repro.core.index import INDEX_AXIS, partition_index
-            from repro.distributed.sharding import partitioned_index_shardings
+            from repro.core.distributed import partitioned_index_shardings
             if mesh is None or INDEX_AXIS not in mesh.axis_names:
                 raise ValueError(
                     f"backend {self.backend!r} partitions the index over "
@@ -534,7 +534,7 @@ class Mapper:
             self.arrays = {k: jnp.asarray(v)
                            for k, v in index_arrays(index).items()}
             if mesh is not None:
-                from repro.distributed.sharding import mapping_chunk_shardings
+                from repro.core.distributed import mapping_chunk_shardings
                 _, rep = mapping_chunk_shardings(mesh)
                 self.arrays = {k: jax.device_put(v, rep)
                                for k, v in self.arrays.items()}

@@ -279,7 +279,7 @@ class HotTileCache:
         self._rng = np.random.default_rng(seed)
         self._rep = None
         if mesh is not None:
-            from repro.distributed.sharding import mapping_chunk_shardings
+            from repro.core.distributed import mapping_chunk_shardings
             _, self._rep = mapping_chunk_shardings(mesh)
         # Replica slots sit AFTER the n_slots primary slots: each holds a
         # byte-identical copy of one of the top-K hottest tiles (by the

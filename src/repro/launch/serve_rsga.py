@@ -21,9 +21,6 @@ with a seeded ``core/faults.FaultPlan`` injected at tile page-in
 the admission loop (SLO classes + saturation-aware shedding); and
 ``--load-sweep 0.5,0.9,1.3,1.8`` serves the same trace shape at several
 offered loads, printing the shed-rate vs p50/p99 curve.
-
-The LLM token-serving twin of this launcher — batched prefill + decode
-with a KV cache — is ``repro.launch.serve``.
 """
 from __future__ import annotations
 
@@ -96,9 +93,7 @@ SHED_CLASSES = (SLOClass("gold", priority=1, deadline=64.0, sheddable=False),
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="MARS RSGA serving launcher: continuous-batching "
-                    "multi-stream read mapping (ServeDriver). For LLM "
-                    "token serving (prefill+decode), see "
-                    "`python -m repro.launch.serve --help`.")
+                    "multi-stream read mapping (ServeDriver).")
     ap.add_argument("--dataset", default="D1",
                     choices=sorted(datasets.DATASETS))
     ap.add_argument("--mode", default="ms_fixed",

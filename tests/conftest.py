@@ -1,8 +1,8 @@
 """Shared fixtures: small synthetic references/read sets.
 
 NOTE: no XLA_FLAGS device-count overrides here — smoke tests and benches
-must see the single real CPU device.  Only launch/dryrun.py forces 512
-placeholder devices (and only in its own process).
+must see the single real CPU device.  Tests of mesh paths force host
+devices in subprocesses of their own.
 """
 import numpy as np
 import pytest

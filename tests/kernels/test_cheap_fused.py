@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import MarsConfig, build_index, pipeline, stages
+from repro.core import MarsConfig, build_index, pipeline, pore_model, stages
 from repro.core.index import bucket_bounds, index_arrays
 from repro.kernels.cheap_fused import cheap_fused
 from repro.kernels.cheap_fused import ref as fused_ref
@@ -194,6 +194,54 @@ def test_hbm_launch_matches_vmem_launch(setup, case, monkeypatch):
     _assert_cheap_equal(hbm, vmem)
     _assert_matches_both(signals, arrays, cfg)
 
+
+@pytest.fixture(scope="module")
+def repeats():
+    """A reference whose seeds occur once, 16 times and 30 times (tandem
+    repeats), so each filter threshold below drops a different share of
+    the hits."""
+    cfg = MarsConfig(hash_bits=12).with_mode("ms_fixed")
+    rng = np.random.default_rng(21)
+
+    def seg(n):
+        return rng.integers(0, 4, size=n, dtype=np.int8)
+    bases = np.concatenate([seg(2000), np.tile(seg(160), 16), seg(1000),
+                            np.tile(seg(120), 30), seg(1000)])
+    table = pore_model.pore_table()
+    ref = simulate.Reference(
+        bases=bases, events_fwd=pore_model.expected_events(bases, table),
+        events_rc=pore_model.expected_events(pore_model.revcomp(bases),
+                                             table),
+        table=table)
+    reads = simulate.sample_reads(ref, 6, signal_len=cfg.signal_len,
+                                  seed=10, junk_frac=0.3)
+    idx = build_index(ref.events_concat, ref.n_events, cfg)
+    return cfg, jnp.asarray(reads.signals), index_arrays(idx)
+
+
+@pytest.mark.parametrize("launch", ["vmem", "hbm"])
+@pytest.mark.parametrize("thresh_freq,thresh_voting",
+                         [(12, 4), (24, 2), (2000, 5)],
+                         ids=["small_genome", "large_genome", "ecoli_d2"])
+def test_fused_under_deployment_thresholds(repeats, monkeypatch, launch,
+                                           thresh_freq, thresh_voting):
+    """The (thresh_freq, thresh_voting) pairs the deployments run:
+    ``datasets.config_for``'s small- and large-genome values and E. coli
+    D2's Section 5.1 values, on either launch: bit-identical to the
+    per-stage program and the vmap ladder."""
+    cfg, signals, arrays = repeats
+    cfg = cfg.replace(thresh_freq=thresh_freq, thresh_voting=thresh_voting)
+    plan = stages.resolve_plan(cfg, stages.PALLAS)
+    counters = pipeline.cheap_phase(signals, arrays, cfg, plan,
+                                    use_fused=False)[3]
+    dropped = int(counters["n_hits_postfreq"].sum()) < int(
+        counters["n_hits_raw"].sum())
+    assert dropped == (thresh_freq < 30)
+    if launch == "hbm":
+        calls = _hbm_launches(monkeypatch)
+    _assert_matches_both(signals, arrays, cfg)
+    if launch == "hbm":
+        assert calls == [signals.shape[0]]
 
 def test_index_past_vmem_falls_back(setup, monkeypatch):
     """An index whose tables would not fit the kernel's VMEM takes the HBM

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro import kernels as K
 from repro.core import build_index, map_chunk, stages
 from repro.core.index import index_arrays
 from repro.signal import datasets
@@ -51,23 +50,6 @@ def d1():
     ref, _ = datasets.build(spec, cfg, 1)
     return cfg, index_arrays(build_index(ref.events_concat, ref.n_events,
                                          cfg))
-
-
-@pytest.fixture
-def mosaic(monkeypatch):
-    """Kernels lower through Mosaic, with jit caches and JAX's persistent
-    compilation cache out of the way (no interpret-mode trace is reused,
-    and nothing unreadable is written)."""
-    from jax.experimental.compilation_cache import compilation_cache
-    monkeypatch.setattr(K, "INTERPRET", False)
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    jax.clear_caches()
-    yield
-    jax.clear_caches()
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
 
 
 def _compile(fn, one_chip, *shapes):

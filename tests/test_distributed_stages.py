@@ -21,7 +21,7 @@ from repro.core import MarsConfig, Mapper, build_index, driver, stages
 from repro.core import partition_index
 from repro.core.index import index_arrays
 from repro.core.pipeline import map_chunk, map_chunk_sharded
-from repro.distributed.sharding import partitioned_index_shardings
+from repro.core.distributed import partitioned_index_shardings
 from repro.launch.mesh import make_mesh
 from repro.signal import simulate
 
@@ -91,6 +91,54 @@ def test_partitioned_query_backends_match_single_device():
     env["PYTHONPATH"] = str(REPO / "src")
     r = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
                        text=True, env=env, cwd=REPO, timeout=560)
+    assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
+    assert "ok" in r.stdout
+
+
+POD_MESH_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import MarsConfig, build_index, stages
+from repro.core.distributed import (partition_index,
+                                    partitioned_index_shardings)
+from repro.core.index import index_arrays
+from repro.core.pipeline import map_chunk, map_chunk_sharded
+from repro.launch.mesh import make_mesh
+from repro.signal import simulate
+
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+cfg = MarsConfig(hash_bits=14).with_mode("ms_fixed")
+ref = simulate.make_reference(50_000, seed=3)
+reads = simulate.sample_reads(ref, 16, signal_len=cfg.signal_len, seed=4,
+                              junk_frac=0.1)
+idx = build_index(ref.events_concat, ref.n_events, cfg)
+arrays = {k: jnp.asarray(v) for k, v in index_arrays(idx).items()}
+out_ref = map_chunk(jnp.asarray(reads.signals), arrays, cfg)
+parts = partition_index(idx, mesh.shape["model"])
+part_sh = partitioned_index_shardings(mesh)
+parts_dev = {k: jax.device_put(jnp.asarray(v), part_sh[k])
+             for k, v in parts.items()}
+for sched in ("ring", "a2a"):
+    out = map_chunk_sharded(jnp.asarray(reads.signals), parts_dev, cfg, mesh,
+                            plan=stages.resolve_plan(cfg, sched))
+    assert np.array_equal(np.asarray(out_ref.mapped), np.asarray(out.mapped)), sched
+    assert np.array_equal(np.asarray(out_ref.t_start), np.asarray(out.t_start)), sched
+    assert np.array_equal(np.asarray(out_ref.score), np.asarray(out.score)), sched
+    assert set(out.counters) == set(stages.CHUNK_COUNTER_SCHEMA), sched
+    for k in stages.CHUNK_COUNTER_SCHEMA:
+        assert int(out.counters[k]) == int(out_ref.counters[k]), (sched, k)
+print("ok")
+"""
+
+
+def test_partitioned_query_on_pod_mesh_matches_single_device():
+    """Both schedules on a 3-axis (pod, data, model) mesh of 8 devices:
+    reads shard over all three axes, the index over 'model' alone."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = str(REPO / "src")
+    r = subprocess.run([sys.executable, "-c", POD_MESH_SCRIPT],
+                       capture_output=True, text=True, env=env, cwd=REPO,
+                       timeout=420)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     assert "ok" in r.stdout
 

@@ -1,34 +1,11 @@
-"""Infrastructure tests: token stream, monitor, reader, HLO analyzer."""
-import time
-
+"""Infrastructure tests: signal reader, HLO analyzer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analysis import hlo
-from repro.data.tokens import TokenStream, TokenStreamState
 from repro.signal import reader as reader_lib
-from repro.train.monitor import StepMonitor
-
-
-def test_token_stream_deterministic_resume():
-    a = TokenStream(1000, 4, 32, seed=5)
-    batches = [a.next_batch() for _ in range(5)]
-    # resume at step 3
-    b = TokenStream(1000, 4, 32, seed=5, start_step=3)
-    again = b.next_batch()
-    np.testing.assert_array_equal(batches[3]["tokens"], again["tokens"])
-
-
-def test_monitor_detects_straggler():
-    mon = StepMonitor(warmup_steps=1, threshold=1.8)
-    for i in range(6):
-        mon.start()
-        time.sleep(0.25 if i == 4 else 0.02)
-        mon.stop()
-    assert len(mon.events) == 1
-    assert mon.events[0].step == 5
 
 
 def test_signal_reader_roundtrip(tmp_path):

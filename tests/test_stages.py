@@ -75,6 +75,43 @@ def test_map_chunk_accepts_no_per_stage_callables():
     assert {"plan", "use_kernels", "n_valid"} <= params
 
 
+# The plan table: which backend serves each stage, and whether the fused
+# cheap-phase kernel engages, for the configs of scripts/kernel_support.py.
+# A silent fallback keeps every parity test green but changes what runs on
+# the chip, so the whole map is pinned here.
+_R, _P = stages.REFERENCE, stages.PALLAS
+_PLAN_CONFIGS = {
+    "ms_fixed": {},
+    "ms_float": {},
+    "rh2": {},
+    "ms_fixed_w13": {"tstat_window": 13},
+}
+_PALLAS_ALL = dict(detect=_P, quantize=_R, seed=_R, query=_P, vote=_R,
+                   sort=_P, dp=_P, finalize=_R)
+_PALLAS_NO_DETECT = {**_PALLAS_ALL, "detect": _R}
+PLAN_TABLE = {
+    ("ms_fixed", "pallas"): (_PALLAS_ALL, "pallas"),
+    ("ms_float", "pallas"): (_PALLAS_NO_DETECT, None),
+    ("rh2", "pallas"): (_PALLAS_NO_DETECT, None),
+    ("ms_fixed_w13", "pallas"): (_PALLAS_NO_DETECT, None),
+    **{(c, b): ({s: (b if s == "query" else _R) for s in stages.STAGE_ORDER},
+                None)
+       for c in _PLAN_CONFIGS for b in ("ring", "a2a", "tiered")},
+}
+
+
+@pytest.mark.parametrize("config,backend", sorted(PLAN_TABLE))
+def test_plan_table(config, backend):
+    mode = config.removesuffix("_w13")
+    cfg = MarsConfig(hash_bits=12).with_mode(mode).replace(
+        **_PLAN_CONFIGS[config])
+    plan = stages.resolve_plan(cfg, backend)
+    want, fused = PLAN_TABLE[config, backend]
+    assert tuple(s for s, _ in plan) == stages.STAGE_ORDER
+    assert dict(plan) == want
+    got = stages.fused_cheap_backend(plan, cfg)
+    assert (got.name if got else None) == fused
+
 # --------------------------------------------------------------------------- #
 # Backend parity
 # --------------------------------------------------------------------------- #
